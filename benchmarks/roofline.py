@@ -30,7 +30,7 @@ def bottleneck_note(r: dict) -> str:
             return "decode reads whole KV/state per token: inherent; batch amortizes params"
         return "attention prob tiles + f32 scan carry hit HBM; fuse (Pallas) / bf16 carry"
     if dom == "collective":
-        if not r["memory"]["fits_16gb"]:
+        if not r["memory"]["fits_hbm"]:
             return "params not dp-sharded -> per-layer all-gathers dominate; FSDP split"
         return "TP all-reduces per layer; overlap with compute / wider TP tiles"
     return "MXU-bound: good; reduce remat to raise useful ratio"
@@ -58,7 +58,7 @@ def emit(rows: list[dict]) -> str:
             f"| {rf['collective_s']:.3g} | **{rf['dominant']}** "
             f"| {rf['useful_flops_ratio']:.2f} "
             f"| {r['memory']['hbm_per_device'] / 2**30:.1f}GiB "
-            f"| {'y' if r['memory']['fits_16gb'] else 'N'} "
+            f"| {'y' if r['memory']['fits_hbm'] else 'N'} "
             f"| {bottleneck_note(r)} |"
         )
     return "\n".join(out)

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common import l2_normalize
+from repro.common import l2_normalize, use_compile_cache
 from repro.core import bkc, buckshot, buckshot_phase1, kmeans, metrics
 from repro.core.bkc import join_to_groups
 from repro.core.microcluster import build_microclusters
@@ -75,8 +75,26 @@ ROWS: list[tuple[str, float, str]] = []
 
 
 def row(name: str, us: float, derived: str) -> None:
+    """Record and print one result; ``derived`` names the platform it ran on.
+    Rows measured in forced-CPU child processes run only where this process
+    is on the CPU too (``cpu_children_refused``), so this process's platform
+    is theirs."""
+    platform = jax.devices()[0].platform
+    derived = ";".join(filter(None, (derived, f"platform={platform}")))
     ROWS.append((name, us, derived))
     print(f"{name},{us:.1f},{derived}")
+
+
+def cpu_children_refused(table: str) -> bool:
+    """Rows measured in forced-CPU child processes. Beside a process that
+    holds an accelerator they would print CPU numbers next to device
+    numbers, so they are refused there."""
+    platform = jax.devices()[0].platform
+    if platform == "cpu":
+        return False
+    print(f"# {table}: skipped — its rows run in forced-CPU child processes,"
+          f" and this process holds the {platform}")
+    return True
 
 
 REPS = max(1, int(os.environ.get("BENCH_REPS", "3")))
@@ -477,6 +495,8 @@ def phase1_distributed():
     import sys
     import textwrap
 
+    if cpu_children_refused("phase1_distributed"):
+        return
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.setdefault("PYTHONPATH", "src")
 
@@ -804,6 +824,8 @@ def stream_oocore():
     import sys
     import textwrap
 
+    if cpu_children_refused("stream_oocore"):
+        return
     n, d, chunks, k = (
         (131_072, 512, 16, 8) if SMALL else (1_048_576, 2048, 64, 16)
     )
@@ -1054,6 +1076,7 @@ def main(argv: list[str] | None = None) -> None:
         help="comma-separated table function names (e.g. kernel_bench,table1)",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     tables = TABLES
     if args.only:

@@ -37,12 +37,14 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from repro.common import use_compile_cache
     from repro.core import metrics
     from repro.core.sampling import buckshot_sample_size
     from repro.distrib import cluster as dc
     from repro.distrib.sharding import make_flat_mesh
     from repro.text.pipeline import prepare_synthetic
 
+    use_compile_cache()
     mesh = make_flat_mesh(args.devices)
     axes = ("data",)
     print(f"mesh: {args.devices} devices; corpus: n={args.n}, vocab={args.vocab}")

@@ -36,13 +36,14 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
 
-    from repro.common import l2_normalize
+    from repro.common import l2_normalize, use_compile_cache
     from repro.configs import get_config
     from repro.core import buckshot, metrics
     from repro.models.registry import get_model
     from repro.serve.engine import ServeEngine
     from repro.text import synth, tfidf
 
+    use_compile_cache()
     corpus = synth.make_corpus(args.n, vocab=512, n_topics=args.k, seed=1)
     labels = jnp.asarray(corpus.labels)
     key = jax.random.PRNGKey(0)

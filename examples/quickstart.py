@@ -24,11 +24,13 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.common import use_compile_cache
 from repro.core import bkc_stream, buckshot_stream, kmeans_stream, metrics
 from repro.text import pipeline
 
 
 def main() -> None:
+    use_compile_cache()
     n, k, chunk = 4000, 12, 1000
     print(f"streaming corpus: n={n}, topics={k}, chunks of {chunk}")
     prep = pipeline.prepare_synthetic_stream(

@@ -24,6 +24,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common import use_compile_cache
 from repro.serve import ClusterService, ServiceConfig
 
 rng = np.random.default_rng(0)
@@ -36,6 +37,7 @@ def texts(n: int, lo: int = 0, hi: int = 40) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = ServiceConfig(
         k=4, dim=128, chunk=64, max_batch=32, queue_cap=128,
         sample_size=24, kmeans_iters=2,

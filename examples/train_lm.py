@@ -27,10 +27,12 @@ def main() -> None:
                     help="simulate preemption at 60%% and auto-resume")
     args = ap.parse_args()
 
+    from repro.common import use_compile_cache
     from repro.configs import get_config
     from repro.models.registry import get_model
     from repro.train.loop import train
 
+    use_compile_cache()
     cfg = get_config(args.arch, reduced=not args.full_width)
     if not args.full_width:
         cfg = cfg.replace(

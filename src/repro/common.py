@@ -3,12 +3,32 @@
 from __future__ import annotations
 
 import functools
+import os
+import pathlib
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 EPS = 1e-12
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point, and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing else
+    is set. Otherwise the cache is the fixed ``<checkout>/.jax_cache``: the
+    path is part of what a cached program is found by, so it is never built
+    from a temporary name, a process id or the time."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def l2_normalize(x: jax.Array, axis: int = -1, eps: float = EPS) -> jax.Array:

@@ -129,7 +129,7 @@ def bkc_fit(
     big_k: int,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     bounded: bool = False,
 ) -> BKCResult:
@@ -185,7 +185,7 @@ def bkc(
     k: int,
     key: jax.Array,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     bounded: bool | None = None,
 ) -> BKCResult:
@@ -219,7 +219,7 @@ def bkc_fit_stream(
     big_k: int,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     checkpoint=None,
     guard=None,
     bounded: bool | None = None,
@@ -306,7 +306,7 @@ def bkc_stream(
     k: int,
     key: jax.Array,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     checkpoint=None,
     guard=None,
     bounded: bool | None = None,

@@ -43,7 +43,7 @@ def phase1_from_sample(
     xs: jax.Array,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     hac: str = "boruvka",
 ) -> tuple[jax.Array, jax.Array]:
     """Phase 1 on already-collected sample rows (s, d): HAC labels + centers.
@@ -72,7 +72,7 @@ def buckshot_phase1(
     sample_idx: jax.Array,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     hac: str = "boruvka",
 ) -> tuple[jax.Array, jax.Array]:
     """Phase 1 alone: sample HAC labels + initial centers.
@@ -96,7 +96,7 @@ def buckshot_fit(
     k: int,
     *,
     kmeans_iters: int = 3,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     hac: str = "boruvka",
     bounded: bool = False,
@@ -126,7 +126,7 @@ def buckshot(
     *,
     sample_size: int | None = None,
     kmeans_iters: int = 3,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     hac: str = "boruvka",
     bounded: bool | None = None,
@@ -152,7 +152,7 @@ def buckshot_stream(
     sample_size: int | None = None,
     kmeans_iters: int = 3,
     tol: float = 0.0,
-    impl: str = "xla",
+    impl: str = "auto",
     hac: str = "boruvka",
     checkpoint=None,
     guard=None,

@@ -394,7 +394,7 @@ def _rounds_for(s: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("impl", "block"))
 def boruvka_mst(
-    xs: jax.Array, *, impl: str = "xla", block: int = 1024
+    xs: jax.Array, *, impl: str = "auto", block: int = 1024
 ) -> MSTEdges:
     """Max spanning forest of the cosine graph of xs (s, d) — single device.
 
@@ -460,7 +460,7 @@ def cut_mst_edges(edges: MSTEdges, n: int, k: int) -> jax.Array:
 
 
 def single_link_labels_boruvka(
-    xs: jax.Array, k: int, *, impl: str = "xla"
+    xs: jax.Array, k: int, *, impl: str = "auto"
 ) -> jax.Array:
     """Drop-in equivalent of single_link_labels, matrix-free Borůvka-style."""
     edges = boruvka_mst(xs, impl=impl)

@@ -88,7 +88,7 @@ def kmeans_step(
     centers: jax.Array,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     reseed: str | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -129,7 +129,7 @@ def kmeans_step_bounded(
     bounds: "ops.Bounds",
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     reseed: str | None = None,
     index: "ops.CenterIndex | None" = None,
 ) -> tuple[jax.Array, "ops.AssignStatsBounded"]:
@@ -181,7 +181,7 @@ def kmeans_fit(
     *,
     max_iters: int = 8,
     tol: float = 1e-4,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     bounded: bool = False,
 ) -> KMeansResult:
@@ -273,7 +273,7 @@ def kmeans(
     max_iters: int = 8,
     tol: float = 1e-4,
     init_centers: jax.Array | None = None,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     bounded: bool | None = None,
 ) -> KMeansResult:
@@ -295,7 +295,7 @@ def assign_batch(
     w: jax.Array | None = None,
     *,
     index: "ops.CenterIndex | None" = None,
-    impl: str = "xla",
+    impl: str = "auto",
 ) -> tuple[jax.Array, jax.Array]:
     """One serving micro-batch: nearest-center assignment through the
     bound-pruned kernel. Batch rows are new every call, so there is no
@@ -322,7 +322,7 @@ def assign_batch(
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
-def _stream_fold_chunk(carry, x, w, centers, *, impl: str = "xla"):
+def _stream_fold_chunk(carry, x, w, centers, *, impl: str = "auto"):
     """Fold one chunk: ONE fused kernel call, monoid-merge into the carry.
 
     Also returns the chunk's (idx, best_sim, weighted objective term) — they
@@ -336,7 +336,7 @@ def _stream_fold_chunk(carry, x, w, centers, *, impl: str = "xla"):
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def _stream_fold_chunk_bounded(
-    carry, x, w, centers, bounds, drift, *, index=None, impl: str = "xla"
+    carry, x, w, centers, bounds, drift, *, index=None, impl: str = "auto"
 ):
     """Bounded sibling of ``_stream_fold_chunk``: same monoid fold, plus the
     refreshed per-row bounds and the chunk's (pruned, real) row counts for the
@@ -489,7 +489,7 @@ def kmeans_fit_stream(
     *,
     max_iters: int = 8,
     tol: float = 1e-4,
-    impl: str = "xla",
+    impl: str = "auto",
     checkpoint=None,
     guard=None,
     bounded: bool | None = None,
@@ -604,7 +604,7 @@ def kmeans_stream(
     *,
     max_iters: int = 8,
     tol: float = 1e-4,
-    impl: str = "xla",
+    impl: str = "auto",
     checkpoint=None,
     guard=None,
 ) -> KMeansResult:

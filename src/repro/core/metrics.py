@@ -19,7 +19,7 @@ from repro.kernels import ops
 @functools.partial(jax.jit, static_argnames=("k",))
 def rss(x: jax.Array, idx: jax.Array, k: int) -> jax.Array:
     """Residual sum of squares vs member-mean centroids (general, any norm)."""
-    sums, counts = ops.label_stats(x, idx, k, impl="xla")
+    sums, counts = ops.label_stats(x, idx, k)
     means = sums / jnp.maximum(counts, 1.0)[:, None]
     sq_norm_x = jnp.sum(x.astype(jnp.float32) ** 2)
     sq_norm_m = jnp.sum(counts * jnp.sum(means * means, axis=1))

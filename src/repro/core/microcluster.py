@@ -38,7 +38,7 @@ def build_microclusters(
     centers: jax.Array,
     big_k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     fused: bool = True,
     bounded: bool = False,
 ) -> tuple[MicroClusters, jax.Array, jax.Array]:

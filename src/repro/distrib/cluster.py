@@ -32,7 +32,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.common import l2_normalize
 from repro.core.bkc import join_to_groups
@@ -204,7 +205,7 @@ def kmeans_distributed(
     *,
     max_iters: int = 8,
     tol: float = 1e-4,
-    impl: str = "xla",
+    impl: str = "auto",
     bounded: bool | None = None,
 ) -> DistClusterResult:
     """PKMeans: the host drives iterations (the paper's job-chaining driver);
@@ -228,7 +229,7 @@ def kmeans_distributed(
     def run(centers, bounds, drift):
         if not bounded:
             return job({"x": x, "w": w}, {"centers": centers})
-        index = ops.build_center_index(centers) if use_index else None
+        index = _center_index(mesh)(centers) if use_index else None
         data = {
             "x": x, "w": w,
             "bidx": bounds.idx, "blo": bounds.lo, "bhi": bounds.hi,
@@ -371,7 +372,7 @@ def kmeans_distributed_stream(
     *,
     max_iters: int = 8,
     tol: float = 1e-4,
-    impl: str = "xla",
+    impl: str = "auto",
     checkpoint=None,
     guard=None,
     bounded: bool | None = None,
@@ -412,7 +413,7 @@ def kmeans_distributed_stream(
             "bounded": True,
             "bounds_blocks": bblocks,
             "drift": drift,
-            "index": ops.build_center_index(centers) if use_index else None,
+            "index": _center_index(mesh)(centers) if use_index else None,
         }
 
     def note_prune(out):
@@ -488,7 +489,7 @@ def bkc_distributed(
     big_k: int,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     bounded: bool | None = None,
 ) -> DistClusterResult:
     """BKC-for-documents as the paper's three MapReduce jobs.
@@ -533,7 +534,7 @@ def bkc_distributed(
     )
     if bounded:
         b = ops.bounds_identity(x.shape[0])
-        index = ops.build_center_index(init_centers) if use_index else None
+        index = _center_index(mesh)(init_centers) if use_index else None
         stats = job1(
             {"x": x, "w": w, "bidx": b.idx, "blo": b.lo, "bhi": b.hi},
             _bounds_bcast(init_centers, None, index),
@@ -565,7 +566,7 @@ def bkc_distributed(
         )
         job3 = make_job(mesh, axes, map_combine, kinds, name="bkc_final_assign")
         b = ops.bounds_identity(x.shape[0])
-        index = ops.build_center_index(centers) if use_index else None
+        index = _center_index(mesh)(centers) if use_index else None
         out = job3(
             {"x": x, "w": w, "bidx": b.idx, "blo": b.lo, "bhi": b.hi},
             _bounds_bcast(centers, None, index),
@@ -591,7 +592,7 @@ def bkc_distributed_stream(
     big_k: int,
     k: int,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     checkpoint=None,
     guard=None,
     bounded: bool | None = None,
@@ -654,7 +655,7 @@ def bkc_distributed_stream(
             "bounded": True,
             "bounds_blocks": None,  # sentinel: no prior pass to carry from
             "drift": None,
-            "index": ops.build_center_index(centers) if use_index else None,
+            "index": _center_index(mesh)(centers) if use_index else None,
         }
 
     stats = None
@@ -803,16 +804,45 @@ def _phase1_init_centers(
         labels = single_link_labels_distributed(
             mesh, axes, xs, k, impl=impl, sweep=sweep, overlap=overlap
         )
-        sums, counts = ops.label_stats(xs, labels, k, impl=impl)
-        return jnp.where(counts[:, None] > 0, l2_normalize(sums), 0.0)
+        centers_of = functools.partial(_label_centers, k=k, impl=impl)
+        return _on_every_device(mesh, centers_of)(xs, labels)
+    phase1 = functools.partial(_phase1_replicated, k=k, impl=impl)
+    return _on_every_device(mesh, phase1)(xs)
 
-    @jax.jit
-    def phase1(xs):
-        labels = single_link_labels_boruvka(xs, k, impl=impl)
-        sums, counts = ops.label_stats(xs, labels, k, impl=impl)
-        return jnp.where(counts[:, None] > 0, l2_normalize(sums), 0.0)
 
-    return phase1(xs)
+def _label_centers(xs, labels, *, k: int, impl: str) -> jax.Array:
+    """(k, d) unit centers of the labelled sample rows; empty labels give 0."""
+    sums, counts = ops.label_stats(xs, labels, k, impl=impl)
+    return jnp.where(counts[:, None] > 0, l2_normalize(sums), 0.0)
+
+
+def _phase1_replicated(xs, *, k: int, impl: str) -> jax.Array:
+    return _label_centers(
+        xs, single_link_labels_boruvka(xs, k, impl=impl), k=k, impl=impl
+    )
+
+
+def _replicated_job(mesh: Mesh, fn):
+    """``fn`` jitted to run whole on every device of the mesh, over
+    replicated inputs, to a replicated result. XLA cannot partition a Pallas
+    kernel, so replicated work on a mesh goes through ``shard_map`` with
+    every spec replicated rather than through the automatic partitioner."""
+    return jax.jit(
+        jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
+    )
+
+
+def _on_every_device(mesh: Mesh, fn):
+    """``_replicated_job`` that first replicates its arguments on the mesh."""
+    rep = NamedSharding(mesh, P())
+    f = _replicated_job(mesh, fn)
+    return lambda *args: f(*(jax.device_put(a, rep) for a in args))
+
+
+@functools.lru_cache(maxsize=8)
+def _center_index(mesh: Mesh):
+    """``ops.build_center_index`` for centers that live replicated on the mesh."""
+    return _on_every_device(mesh, ops.build_center_index)
 
 
 def buckshot_distributed(
@@ -825,7 +855,7 @@ def buckshot_distributed(
     *,
     sample_size: int,
     kmeans_iters: int = 3,
-    impl: str = "xla",
+    impl: str = "auto",
     hac: str = "replicated",
     sweep: str = "auto",
     overlap: bool = True,
@@ -997,7 +1027,7 @@ def buckshot_distributed_stream(
     *,
     sample_size: int,
     kmeans_iters: int = 3,
-    impl: str = "xla",
+    impl: str = "auto",
     hac: str = "replicated",
     sweep: str = "auto",
     overlap: bool = True,

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.distrib.sharding import data_spec, ring_permutation
 
 
@@ -145,7 +144,7 @@ def make_job(
         )
         # check_vma=False: the 'gather' reducer (all_gather tiled) produces
         # replicated values that the static VMA inference cannot prove.
-        f = shard_map(
+        f = jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )
         return f(data, bcast)
@@ -464,7 +463,7 @@ class FoldJob:
 
         @jax.jit
         def first(data, bcast):
-            f = shard_map(
+            f = jax.shard_map(
                 inner_first,
                 mesh=mesh,
                 in_specs=data_specs(data, bcast),
@@ -475,7 +474,7 @@ class FoldJob:
 
         @jax.jit
         def step(carry, data, bcast):
-            f = shard_map(
+            f = jax.shard_map(
                 inner_step,
                 mesh=mesh,
                 in_specs=(carry_spec, *data_specs(data, bcast)),
@@ -486,7 +485,7 @@ class FoldJob:
 
         @jax.jit
         def finalize(carry):
-            f = shard_map(
+            f = jax.shard_map(
                 inner_finalize,
                 mesh=mesh,
                 in_specs=(carry_spec,),

@@ -690,7 +690,7 @@ def boruvka_mst_distributed(
     axes: tuple[str, ...],
     xs: jax.Array,
     *,
-    impl: str = "xla",
+    impl: str = "auto",
     pre_reduce: bool = True,
     merge: str = "comp",
     sweep: str = "auto",
@@ -1141,7 +1141,7 @@ def synthetic_merge_rounds(
 
 def single_link_labels_distributed(
     mesh: Mesh, axes: tuple[str, ...], xs: jax.Array, k: int, *,
-    impl: str = "xla", pre_reduce: bool = True, sweep: str = "auto",
+    impl: str = "auto", pre_reduce: bool = True, sweep: str = "auto",
     overlap: bool = True,
 ) -> jax.Array:
     edges = boruvka_mst_distributed(

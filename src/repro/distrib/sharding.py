@@ -12,17 +12,23 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
-def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """jax.make_mesh with Auto axis types where the runtime supports them."""
-    from repro.compat import make_mesh as _make_mesh
-
-    return _make_mesh(shape, axes)
+def make_mesh(
+    shape: Sequence[int], axes: Sequence[str], devices=None
+) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types: the device order follows the
+    chips' physical topology, so mesh neighbours are interconnect
+    neighbours, not whatever order ``jax.devices()`` lists."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_flat_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     """1-D mesh over all (or first n) local devices — the clustering layout."""
     devs = jax.devices()[: n_devices or len(jax.devices())]
-    return Mesh(np.array(devs), (axis,))
+    return make_mesh((len(devs),), (axis,), devices=devs)
 
 
 def make_pod_mesh(
@@ -51,7 +57,7 @@ def make_pod_mesh(
             f"need {n_pods * pod_size} devices for a ({n_pods}, {pod_size})"
             f" pod mesh, have {len(devs)}"
         )
-    return Mesh(np.array(devs).reshape(n_pods, pod_size), axes)
+    return make_mesh((n_pods, pod_size), axes, devices=devs)
 
 
 def tier_sizes(mesh: Mesh, axes: tuple[str, ...]) -> tuple[int, ...]:

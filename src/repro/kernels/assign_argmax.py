@@ -27,6 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import dot_precision
 
 NEG = float(jnp.finfo(jnp.float32).min)
 
@@ -49,6 +52,7 @@ def _kernel(x_ref, c_ref, idx_ref, sim_ref, *, k_real: int, bk: int):
         c,
         (((1,), (1,)), ((), ())),  # contract on d: (BN, d) x (BK, d) -> (BN, BK)
         preferred_element_type=jnp.float32,
+        precision=dot_precision(x.dtype),
     )
     # mask padded center columns (global col id >= k_real)
     col = j * bk + jax.lax.broadcasted_iota(jnp.int32, sims.shape, 1)
@@ -63,6 +67,26 @@ def _kernel(x_ref, c_ref, idx_ref, sim_ref, *, k_real: int, bk: int):
     better = local_sim > best_sim  # strict: earlier tiles win ties
     sim_ref[...] = jnp.where(better, local_sim, best_sim)
     idx_ref[...] = jnp.where(better, local_idx, idx_ref[...])
+
+
+_MIB = 1024 * 1024
+# Mosaic grants a kernel 16 MiB of scoped VMEM unless asked for more; a v5e
+# TensorCore has 128 MiB. Kernels whose blocks outgrow the default ask for
+# what they need, with headroom, up to this cap.
+_VMEM_CAP = 100 * _MIB
+
+
+def vmem_params(need: int) -> pltpu.CompilerParams:
+    """Compiler params granting ``need`` bytes of scoped VMEM plus 4 MiB of
+    headroom — never below Mosaic's 16 MiB default, never above the cap."""
+    limit = min(max(need + 4 * _MIB, 16 * _MIB), _VMEM_CAP)
+    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
+
+
+def split_bytes(*operands: tuple[int, int]) -> int:
+    """VMEM a HIGHEST-precision f32 dot adds: Mosaic splits each f32 operand
+    of ``(elements, itemsize)`` into bf16 parts (about twice its bytes)."""
+    return sum(2 * n * 4 for n, isz in operands if isz == 4)
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:

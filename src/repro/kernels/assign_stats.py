@@ -53,16 +53,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Shared with the standalone assign kernel: same tiling, same tie semantics.
 from repro.kernels import ref
-from repro.kernels.assign_argmax import BK, BN, NEG, _pad_to
-from repro.kernels.ref import BIG, BIG_I, PRUNE_MARGIN
+from repro.kernels.assign_argmax import (
+    BK,
+    BN,
+    NEG,
+    _pad_to,
+    split_bytes,
+    vmem_params,
+)
+from repro.kernels.ref import BIG, BIG_I, PRECISION, PRUNE_MARGIN, dot_precision
 
 BD = 512  # feature columns per label_stats accumulator tile
 # VMEM cap for the fused kernel's resident (kp, d) f32 sums accumulator; the
 # old implicit ceiling was one tile of k~1k x d=2048 (8 MiB, DESIGN.md §6).
 ACC_BUDGET = 8 * 1024 * 1024
+
+
+def _fused_vmem(
+    *, bn: int, bk: int, dp: int, isz: int, kp: int, acc_cols: int,
+    extra: int = 0,
+):
+    """Scoped-VMEM request for one fused-kernel call, from its block shapes.
+
+    Every pipelined block is double-buffered — the resident (kp, acc_cols)
+    sums accumulator included. The combine step keeps an f32 copy of the x
+    tile and (kp, bn) one-hot temporaries next to them, and both dots split
+    their f32 operands for HIGHEST precision.
+    """
+    blocks = 2 * (bn * dp * isz + bk * dp * isz + kp * acc_cols * 4)
+    temps = bn * dp * 4 + 3 * kp * bn * 4 + 2 * bn * bk * 4
+    split = split_bytes((bn * dp, isz), (bk * dp, isz)) + split_bytes(
+        (kp * bn, 4), (bn * acc_cols, 4)
+    )
+    return vmem_params(blocks + temps + split + extra)
 
 
 def _kernel(
@@ -102,6 +129,7 @@ def _kernel(
         c,
         (((1,), (1,)), ((), ())),  # contract on d: (BN, d) x (BK, d) -> (BN, BK)
         preferred_element_type=jnp.float32,
+        precision=dot_precision(x.dtype),
     )
     # mask padded center columns (global col id >= k_real)
     col = j * bk + jax.lax.broadcasted_iota(jnp.int32, sims.shape, 1)
@@ -139,6 +167,7 @@ def _kernel(
             xf[:, : sums_ref.shape[1]],  # accumulator may cover a d prefix
             (((1,), (0,)), ((), ())),  # (kp, BN) @ (BN, bd_sums)
             preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
         counts_ref[...] += jnp.sum(hot_w, axis=1, keepdims=True)
         rowsq = jnp.sum(xf * xf, axis=1)  # (BN,)
@@ -212,6 +241,10 @@ def assign_stats_pallas(
             jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
+        compiler_params=_fused_vmem(
+            bn=bn, bk=bk, dp=dp, isz=xp.dtype.itemsize, kp=kp,
+            acc_cols=bd_sums,
+        ),
         interpret=interpret,
     )(xp, cp, wp)
     idx_n = idx[:n, 0]
@@ -269,9 +302,7 @@ def _bounded_kernel(
     sim0_ref,
     perm_ref,
     rep_ref,
-    ap_ref,
-    an_ref,
-    bm_ref,
+    cone_ref,
     idx_ref,
     sim_ref,
     sec_ref,
@@ -304,13 +335,16 @@ def _bounded_kernel(
     x = x_ref[...]  # (BN, d)
     act = act_ref[...] > 0  # (BN, 1) row still needs the sweep
     rsq = rsq_ref[...]  # (BN, 1) ‖x‖²
-    rep = rep_ref[...]  # (1, d) slab representative (unit or zero)
+    rep = rep_ref[...]  # (8, d): row 0 the slab representative, rest zero
     s = jax.lax.dot_general(
-        x, rep, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (BN, 1)
+        x.astype(jnp.float32), rep, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=PRECISION,
+    )[:, 0:1]  # (BN, 1)
     t = jnp.sqrt(jnp.maximum(rsq - s * s, 0.0))
     ub = (
-        jnp.maximum(ap_ref[0, 0] * s, an_ref[0, 0] * s) + bm_ref[0, 0] * t
+        jnp.maximum(cone_ref[0, j] * s, cone_ref[1, j] * s)
+        + cone_ref[2, j] * t
     )  # (BN, 1) cone bound on any member similarity
 
     cur = sim_ref[...]  # running best only grows, so the skip test is safe
@@ -320,9 +354,10 @@ def _bounded_kernel(
     def _sweep():
         c = c_ref[...]  # (BK, d) permuted centers
         sims = jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            x, c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=dot_precision(x.dtype),
         )  # (BN, BK)
-        orig = perm_ref[...][:, 0][None, :]  # (1, BK) original ids; -1 = pad
+        orig = perm_ref[...]  # (1, BK) original ids; -1 = pad
         valid = jnp.logical_and(orig >= 0, act)
         sims = jnp.where(valid, sims, NEG)
         lmax = jnp.max(sims, axis=1, keepdims=True)
@@ -376,6 +411,7 @@ def _bounded_kernel(
             xf[:, : sums_ref.shape[1]],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=PRECISION,
         )
         counts_ref[...] += jnp.sum(hot_w, axis=1, keepdims=True)
         rowsq = jnp.sum(xf * xf, axis=1)
@@ -425,7 +461,7 @@ def assign_stats_bounded_pallas(
     cperm = centers[perm]
     xp = _pad_to(_pad_to(x, 0, bn), 1, dmult)
     cp = _pad_to(_pad_to(cperm, 0, bk), 1, dmult)
-    permp = _pad_to(perm.astype(jnp.int32)[:, None] + 1, 0, bk) - 1  # pad -> -1
+    permp = _pad_to(perm.astype(jnp.int32)[None, :] + 1, 1, bk) - 1  # pad -> -1
     wv = jnp.ones((n,), jnp.float32) if w is None else w.astype(jnp.float32)
     wp = _pad_to(wv[:, None], 0, bn)
     np_, dp = xp.shape
@@ -436,14 +472,14 @@ def assign_stats_bounded_pallas(
 
     # ---- row-level bound prep (XLA side: O(n·d), dwarfed by the sweep)
     xf = x.astype(jnp.float32)
-    rowsq = jnp.einsum("nd,nd->n", xf, xf)
+    rowsq = jnp.einsum("nd,nd->n", xf, xf, precision=PRECISION)
     rownorm = jnp.sqrt(rowsq)
     ok, pidx, lo_adj, hi_adj = ref.deflate_bounds(
         prev_idx, lo, hi, rownorm, drift
     )
     pruned = jnp.logical_and(ok, lo_adj > hi_adj + margin)
     sim_prev = jnp.einsum(
-        "nd,nd->n", xf, centers[pidx].astype(jnp.float32)
+        "nd,nd->n", xf, centers[pidx].astype(jnp.float32), precision=PRECISION
     )  # settled rows' final similarity, without the k sweep
     act = jnp.where(pruned, 0.0, 1.0).astype(jnp.float32)
     idx0 = jnp.where(pruned, pidx, -1).astype(jnp.int32)
@@ -460,19 +496,25 @@ def assign_stats_bounded_pallas(
     mean = jnp.sum(c3 * m3[..., None], axis=1) / jnp.maximum(cnt, 1.0)[:, None]
     mnorm = jnp.sqrt(jnp.sum(mean * mean, axis=1, keepdims=True))
     rep = mean / jnp.maximum(mnorm, 1e-12)  # (ns, dp); empty slab -> 0
-    a = jnp.einsum("sbd,sd->sb", c3, rep)
+    a = jnp.einsum("sbd,sd->sb", c3, rep, precision=PRECISION)
     csq = jnp.sum(c3 * c3, axis=2)
     bperp = jnp.sqrt(jnp.maximum(csq - a * a, 0.0))
     nonempty = cnt > 0
     a_pos = jnp.where(
         nonempty, jnp.max(jnp.where(m3, a, NEG), axis=1), 0.0
-    )[:, None]
+    )
     a_neg = jnp.where(
         nonempty, jnp.min(jnp.where(m3, a, BIG), axis=1), 0.0
-    )[:, None]
+    )
     b_max = jnp.where(
         nonempty, jnp.max(jnp.where(m3, bperp, 0.0), axis=1), 0.0
-    )[:, None]
+    )
+    # Mosaic tiles a VMEM block in (8, 128): each slab's representative rides
+    # as row 0 of its own zero-padded 8-row block, and the three per-slab
+    # cone scalars live whole in SMEM, read by slab index.
+    rep8 = jnp.zeros((ns, 8, dp), jnp.float32).at[:, 0].set(rep)
+    rep8 = rep8.reshape(ns * 8, dp)
+    cone = jnp.stack([a_pos, a_neg, b_max])  # (3, ns)
 
     if bd is None:
         bd = ACC_BUDGET // (kp * 4)
@@ -489,11 +531,9 @@ def assign_stats_bounded_pallas(
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, dp), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
+            pl.BlockSpec((8, dp), lambda i, j: (j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
@@ -513,8 +553,15 @@ def assign_stats_bounded_pallas(
             jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
+        compiler_params=_fused_vmem(
+            bn=bn, bk=bk, dp=dp, isz=xp.dtype.itemsize, kp=kp,
+            acc_cols=bd_sums,
+            # the slab's (8, d) representative block, and the cone dot's f32
+            # copy of the x tile with its precision split
+            extra=2 * 8 * dp * 4 + bn * dp * 4 + split_bytes((bn * dp, 4)),
+        ),
         interpret=interpret,
-    )(xp, cp, wp, actp, rsqp, idx0p, sim0p, permp, rep, a_pos, a_neg, b_max)
+    )(xp, cp, wp, actp, rsqp, idx0p, sim0p, permp, rep8, cone)
     idx_n = idx[:n, 0]
     sim_n = sim[:n, 0]
     if bd_sums < d:
@@ -569,6 +616,7 @@ def _label_stats_kernel(idx_ref, w_ref, x_ref, sums_ref, counts_ref):
         x.astype(jnp.float32),
         (((1,), (0,)), ((), ())),  # (kp, BN) @ (BN, BD)
         preferred_element_type=jnp.float32,
+        precision=PRECISION,
     )
 
     @pl.when(i == 0)

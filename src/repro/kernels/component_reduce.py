@@ -46,8 +46,8 @@ from repro.kernels.ref import BIG_I
 
 NEG = float(jnp.finfo(jnp.float32).min)
 
-BN = 256  # candidate rows per tile
-BCOMP = 512  # component segments per tile
+BN = 512  # candidate rows per tile (lane-dense blocks: a 128 multiple)
+BCOMP = 256  # component segments per tile
 
 
 def _kernel(w_ref, j_ref, row_ref, comp_ref, bw_ref, brow_ref, bj_ref, *,
@@ -61,26 +61,26 @@ def _kernel(w_ref, j_ref, row_ref, comp_ref, bw_ref, brow_ref, bj_ref, *,
         brow_ref[...] = jnp.full_like(brow_ref, BIG_I)
         bj_ref[...] = jnp.full_like(bj_ref, -1)
 
-    w = w_ref[...][:, 0]  # (BN,) f32 candidate weights
-    col = j_ref[...][:, 0]  # (BN,) int32 candidate columns
-    rows = row_ref[...][:, 0]  # (BN,) int32 global row ids
-    comp = comp_ref[...][:, 0]  # (BN,) int32 dense component ids
+    # lane-dense (1, BN) rows: they broadcast against the (BCOMP, BN)
+    # membership tile with no relayout
+    w = w_ref[...]  # f32 candidate weights
+    col = j_ref[...]  # int32 candidate columns
+    rows = row_ref[...]  # int32 global row ids
+    comp = comp_ref[...]  # int32 dense component ids
 
-    bn = w.shape[0]
+    bn = w.shape[1]
     bins = i * bcomp + jax.lax.broadcasted_iota(jnp.int32, (bcomp, bn), 0)
-    hot = bins == comp[None, :]  # (BCOMP, BN) membership, VMEM only
+    hot = bins == comp  # (BCOMP, BN) membership, VMEM only
     has_any = jnp.any(hot, axis=1, keepdims=True)  # (BCOMP, 1)
 
-    wmask = jnp.where(hot, w[None, :], NEG)
+    wmask = jnp.where(hot, w, NEG)
     tile_w = jnp.max(wmask, axis=1, keepdims=True)  # (BCOMP, 1)
     # lowest ROW ID among the members achieving the tile max (row ids are
     # globally unique, so the winner — and its column — is unique too)
-    cand = jnp.logical_and(hot, w[None, :] == tile_w)
-    tile_row = jnp.min(
-        jnp.where(cand, rows[None, :], BIG_I), axis=1, keepdims=True
-    )
-    sel = jnp.logical_and(cand, rows[None, :] == tile_row)
-    tile_j = jnp.sum(jnp.where(sel, col[None, :], 0), axis=1, keepdims=True)
+    cand = jnp.logical_and(hot, w == tile_w)
+    tile_row = jnp.min(jnp.where(cand, rows, BIG_I), axis=1, keepdims=True)
+    sel = jnp.logical_and(cand, rows == tile_row)
+    tile_j = jnp.sum(jnp.where(sel, col, 0), axis=1, keepdims=True)
 
     best_w = bw_ref[...]
     best_row = brow_ref[...]
@@ -115,26 +115,27 @@ def component_best_edge_pallas(
     segments get (f32.min, BIG_I, -1).
     """
     r = row_w.shape[0]
-    bn = min(bn, max(8, r))
+    # a (1, bn) block spans the whole row axis or a 128-lane multiple (BN)
+    bn = min(bn, r)
     cp = c + ((-c) % 8)  # sublane-align the segment dimension
     bcomp = min(bcomp, cp)
     cp = cp + ((-cp) % bcomp)  # comp-grid divisible; surplus bins stay empty
 
-    # pad rows are tagged comp id c (out of range): they match no tile bin
-    wp = _pad_to(row_w.astype(jnp.float32)[:, None], 0, bn)
-    jp = _pad_to(row_j.astype(jnp.int32)[:, None], 0, bn)
-    rp = _pad_to(rows.astype(jnp.int32)[:, None], 0, bn)
-    compp = _pad_to(comp.astype(jnp.int32)[:, None] + 1, 0, bn) - 1  # pad -> -1
-    grid = (cp // bcomp, wp.shape[0] // bn)
+    # pad rows are tagged comp id -1 (out of range): they match no tile bin
+    wp = _pad_to(row_w.astype(jnp.float32)[None, :], 1, bn)
+    jp = _pad_to(row_j.astype(jnp.int32)[None, :], 1, bn)
+    rp = _pad_to(rows.astype(jnp.int32)[None, :], 1, bn)
+    compp = _pad_to(comp.astype(jnp.int32)[None, :] + 1, 1, bn) - 1  # pad -> -1
+    grid = (cp // bcomp, wp.shape[1] // bn)
 
     best_w, best_row, best_j = pl.pallas_call(
         functools.partial(_kernel, bcomp=bcomp),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bcomp, 1), lambda i, j: (i, 0)),

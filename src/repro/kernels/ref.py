@@ -30,6 +30,19 @@ BIG_I = int(jnp.iinfo(jnp.int32).max)
 # strict margin never prunes).
 PRUNE_MARGIN = 1e-4
 
+# Contraction precision of every f32 dot on the assignment, statistics and
+# edge paths, in the Pallas kernels and in these oracles alike. A TPU runs an
+# f32 dot at DEFAULT precision as one bf16 pass (~3 significant digits);
+# HIGHEST keeps it f32, so the kernel, the XLA path and a float64 reference
+# agree on the chip as they do on the CPU, where it changes nothing.
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def dot_precision(dtype) -> jax.lax.Precision | None:
+    """``PRECISION`` for f32 operands. A bf16 product is exact in the f32
+    accumulator at DEFAULT, and Mosaic accepts HIGHEST for f32 only."""
+    return PRECISION if jnp.dtype(dtype) == jnp.float32 else None
+
 
 def assign_argmax(x: jax.Array, centers: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Nearest-center assignment by dot-product similarity.
@@ -43,7 +56,8 @@ def assign_argmax(x: jax.Array, centers: jax.Array) -> tuple[jax.Array, jax.Arra
       best_sim: (n,) f32    max_k <x, c_k>
     """
     sims = jnp.einsum(
-        "nd,kd->nk", x, centers, preferred_element_type=jnp.float32
+        "nd,kd->nk", x, centers,
+        preferred_element_type=jnp.float32, precision=PRECISION,
     )
     best_idx = jnp.argmax(sims, axis=1).astype(jnp.int32)
     best_sim = jnp.max(sims, axis=1).astype(jnp.float32)
@@ -70,7 +84,8 @@ def cluster_stats(
     """
     one_hot = jax.nn.one_hot(idx, k, dtype=jnp.float32)  # (n, k)
     sums = jnp.einsum(
-        "nk,nd->kd", one_hot, x, preferred_element_type=jnp.float32
+        "nk,nd->kd", one_hot, x,
+        preferred_element_type=jnp.float32, precision=PRECISION,
     )
     counts = jnp.sum(one_hot, axis=0)
     return sums, counts
@@ -104,10 +119,13 @@ def assign_stats(
     one_hot = jax.nn.one_hot(idx, k, dtype=jnp.float32)  # (n, k)
     if w is not None:
         one_hot = one_hot * w.astype(jnp.float32)[:, None]
-    sums = jnp.einsum("nk,nd->kd", one_hot, x, preferred_element_type=jnp.float32)
+    sums = jnp.einsum(
+        "nk,nd->kd", one_hot, x,
+        preferred_element_type=jnp.float32, precision=PRECISION,
+    )
     counts = jnp.sum(one_hot, axis=0)
     rowsq = jnp.sum(x.astype(jnp.float32) ** 2, axis=1)  # (n,)
-    sumsq = jnp.einsum("nk,n->k", one_hot, rowsq)
+    sumsq = jnp.einsum("nk,n->k", one_hot, rowsq, precision=PRECISION)
     member = jnp.where(one_hot > 0, best_sim[:, None], BIG)  # (n, k)
     min_sim = jnp.min(member, axis=0) if x.shape[0] else jnp.full((k,), BIG)
     min_sim = jnp.where(counts > 0, min_sim, BIG)
@@ -128,7 +146,7 @@ def assign_stats_scatter(
     idx, best_sim = assign_argmax(x, centers)
     xf = x.astype(jnp.float32)
     # einsum, not sum(x*x): XLA CPU lowers the contraction ~3x faster
-    rowsq = jnp.einsum("nd,nd->n", xf, xf)
+    rowsq = jnp.einsum("nd,nd->n", xf, xf, precision=PRECISION)
     if w is not None:
         wf = w.astype(jnp.float32)
         xf = xf * wf[:, None]
@@ -211,13 +229,14 @@ def _bounded_assign(
     k = centers.shape[0]
     neg = jnp.finfo(jnp.float32).min
     xf = x.astype(jnp.float32)
-    rowsq = jnp.einsum("nd,nd->n", xf, xf)
+    rowsq = jnp.einsum("nd,nd->n", xf, xf, precision=PRECISION)
     rownorm = jnp.sqrt(rowsq)
     ok, pidx, lo_adj, hi_adj = deflate_bounds(prev_idx, lo, hi, rownorm, drift)
     pruned = jnp.logical_and(ok, lo_adj > hi_adj + margin)
 
     sims = jnp.einsum(
-        "nd,kd->nk", x, centers, preferred_element_type=jnp.float32
+        "nd,kd->nk", x, centers,
+        preferred_element_type=jnp.float32, precision=PRECISION,
     )
     brute_idx = jnp.argmax(sims, axis=1).astype(jnp.int32)
     brute_best = jnp.max(sims, axis=1).astype(jnp.float32)
@@ -279,9 +298,12 @@ def assign_stats_bounded(
     one_hot = jax.nn.one_hot(idx, k, dtype=jnp.float32)  # (n, k)
     if w is not None:
         one_hot = one_hot * w.astype(jnp.float32)[:, None]
-    sums = jnp.einsum("nk,nd->kd", one_hot, x, preferred_element_type=jnp.float32)
+    sums = jnp.einsum(
+        "nk,nd->kd", one_hot, x,
+        preferred_element_type=jnp.float32, precision=PRECISION,
+    )
     counts = jnp.sum(one_hot, axis=0)
-    sumsq = jnp.einsum("nk,n->k", one_hot, rowsq)
+    sumsq = jnp.einsum("nk,n->k", one_hot, rowsq, precision=PRECISION)
     member = jnp.where(one_hot > 0, best_sim[:, None], BIG)  # (n, k)
     min_sim = jnp.min(member, axis=0) if x.shape[0] else jnp.full((k,), BIG)
     min_sim = jnp.where(counts > 0, min_sim, BIG)
@@ -354,7 +376,10 @@ def label_stats(
     one_hot = jax.nn.one_hot(idx, k, dtype=jnp.float32)  # (n, k); oob -> 0 row
     if w is not None:
         one_hot = one_hot * w.astype(jnp.float32)[:, None]
-    sums = jnp.einsum("nk,nd->kd", one_hot, x, preferred_element_type=jnp.float32)
+    sums = jnp.einsum(
+        "nk,nd->kd", one_hot, x,
+        preferred_element_type=jnp.float32, precision=PRECISION,
+    )
     counts = jnp.sum(one_hot, axis=0)
     return sums, counts
 
@@ -441,7 +466,7 @@ def sim_best_edge(
         xs_rows,
         xs_all,
         (((1,), (1,)), ((), ())),  # contract on d — same form as the kernel
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=PRECISION,
     )
     return best_edge(sim, labels_row, labels_col)
 

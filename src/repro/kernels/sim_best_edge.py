@@ -41,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.assign_argmax import _pad_to
+from repro.kernels.assign_argmax import _pad_to, split_bytes, vmem_params
+from repro.kernels.ref import dot_precision
 
 NEG = float(jnp.finfo(jnp.float32).min)
 
@@ -75,6 +76,7 @@ def _kernel(
         xc,
         (((1,), (1,)), ((), ())),  # contract on d: (BR, BD) x (BC, BD) -> (BR, BC)
         preferred_element_type=jnp.float32,
+        precision=dot_precision(xr.dtype),
     )
 
     # mask + rowmax + argmax only once the contraction is complete
@@ -160,6 +162,13 @@ def sim_best_edge_pallas(
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((br, bc), jnp.float32)],
+        # double-buffered row/column slices, the (br, bc) accumulator with
+        # three same-size temporaries, and the precision split of the dot
+        compiler_params=vmem_params(
+            2 * (br + bc) * bd * xr.dtype.itemsize
+            + 4 * br * bc * 4
+            + split_bytes((br * bd, xr.dtype.itemsize), (bc * bd, xr.dtype.itemsize))
+        ),
         interpret=interpret,
     )(xr, xc, lr, lc)
     out_j = best_j[:r, 0]

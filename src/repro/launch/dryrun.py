@@ -24,12 +24,11 @@ import traceback  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.launch.hlo_costs import parse_hlo_costs, xla_cost_analysis  # noqa: E402
+from repro.launch.hlo_costs import device_peaks, parse_hlo_costs  # noqa: E402
 
-# v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-LINK_BW = 50e9  # bytes/s ICI per link
+# The chip the cells are projected onto (``jax.Device.device_kind``); its
+# peaks come from hlo_costs.DEVICE_PEAKS.
+DEVICE_KIND = "TPU v5 lite"
 
 
 def _finish_report(
@@ -38,7 +37,7 @@ def _finish_report(
 ):
     """Shared roofline/memory/collective reporting for any compiled cell."""
     mem = compiled.memory_analysis()
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     parsed = parse_hlo_costs(hlo)  # while bodies x trip count (hlo_costs.py)
     coll = parsed["collectives"]
@@ -47,9 +46,10 @@ def _finish_report(
     flops_dev = parsed["flops"]
     bytes_dev = parsed["bytes"]
 
-    compute_term = flops_dev / PEAK_FLOPS
-    memory_term = bytes_dev / HBM_BW
-    collective_term = coll["total"] / LINK_BW
+    peaks = device_peaks(DEVICE_KIND)
+    compute_term = flops_dev / peaks["flops_bf16"]
+    memory_term = bytes_dev / peaks["hbm_bytes_per_s"]
+    collective_term = coll["total"] / peaks["ici_link_bytes_per_s"]
     dominant = max(
         [("compute", compute_term), ("memory", memory_term),
          ("collective", collective_term)],
@@ -68,6 +68,7 @@ def _finish_report(
         "kind": kind,
         "mesh": mesh_name,
         "n_devices": n_dev,
+        "device_kind": DEVICE_KIND,
         "ok": True,
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),
@@ -77,7 +78,7 @@ def _finish_report(
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
             "hbm_per_device": hbm_per_dev,
-            "fits_16gb": bool(hbm_per_dev < 16e9),
+            "fits_hbm": bool(hbm_per_dev < peaks["hbm_bytes"]),
         },
         "cost": {
             "flops_per_device": flops_dev,

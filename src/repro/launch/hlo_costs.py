@@ -33,11 +33,30 @@ import math
 import re
 from dataclasses import dataclass, field
 
-from repro.compat import cost_analysis as xla_cost_analysis  # noqa: F401
-# Re-exported here because this module is the cost-model entry point:
-# ``xla_cost_analysis(compiled)`` normalizes the JAX API drift where
-# ``Compiled.cost_analysis()`` returns a one-element list on 0.4.x and a
-# plain dict on newer releases.
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud TPU documentation, "TPU v5e" system architecture — 197
+# TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interchip
+# interconnect over 4 links per chip.
+DEVICE_PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "ici_link_bytes_per_s": 1600e9 / 8 / 4,
+    },
+}
+
+
+def device_peaks(device_kind: str) -> dict[str, float]:
+    """The peaks of ``device_kind``; a device not in the table is an error."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them,"
+            " with their source, to DEVICE_PEAKS"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

@@ -9,7 +9,7 @@ carries TP / vocab / expert sharding and stays inside a pod (ICI, not DCN).
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+from repro.distrib.sharding import make_mesh
 from repro.models.common import MeshPolicy
 
 

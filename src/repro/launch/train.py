@@ -55,7 +55,7 @@ def main() -> int:
     from repro.train.step import make_train_step
 
     axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-    from repro.compat import make_mesh
+    from repro.distrib.sharding import make_mesh
 
     mesh = make_mesh(shape, axes)
     policy = policy_for(mesh)

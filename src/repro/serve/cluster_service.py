@@ -91,7 +91,7 @@ class ServiceConfig:
     chunk: int = 1024  # stream chunk for fit/refit passes
     max_batch: int = 64  # rows per jitted micro-batch slab
     queue_cap: int = 256  # admission queue capacity, in ROWS
-    impl: str = "xla"
+    impl: str = "auto"
     bounded: bool = True  # serve assigns through the bound-pruned kernel
     sample_size: int | None = None  # buckshot sample (None = paper sqrt(kn))
     kmeans_iters: int = 3

@@ -234,7 +234,6 @@ def test_compressed_psum_close_to_exact():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.distrib.compression import compressed_psum
     from repro.distrib.sharding import make_flat_mesh, shard_rows
 
@@ -245,7 +244,7 @@ def test_compressed_psum_close_to_exact():
     def f(v):
         return jax.lax.psum(v, ("data",)), compressed_psum(v, ("data",))
 
-    exact, approx = jax.jit(shard_map(
+    exact, approx = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("data"), out_specs=(P(), P()), check_vma=False
     ))(xs)
     rel = float(jnp.max(jnp.abs(exact - approx)) / jnp.max(jnp.abs(exact)))

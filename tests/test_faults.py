@@ -246,6 +246,23 @@ def test_pallas_failure_degrades_to_xla(_pallas_armed):
     np.testing.assert_array_equal(np.asarray(idx2), np.asarray(want2))
 
 
+def test_real_pallas_failure_propagates(_pallas_armed, monkeypatch):
+    """A Pallas failure that no injected fault caused ends the call: it is
+    never replaced by XLA in silence."""
+    from repro.kernels import assign_argmax as kmod
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(kmod, "assign_argmax_pallas", refused)
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(43, 16)).astype(np.float32))
+    c = jnp.asarray(rng.normal(size=(3, 16)).astype(np.float32))
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        ops.assign_argmax(x, c, impl="pallas")
+    assert not ops.pallas_degraded()
+
+
 # ------------------------------------------------------------ prefetcher
 
 

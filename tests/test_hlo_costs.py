@@ -15,8 +15,9 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.launch.hlo_costs import parse_hlo_costs, xla_cost_analysis
+from repro.launch.hlo_costs import parse_hlo_costs
 
 
 def _costs(fn, *args):
@@ -30,7 +31,7 @@ def test_single_matmul_flops():
     want = 2 * 256**3
     assert abs(r["flops"] - want) / want < 0.01
     # parser should agree with XLA's own analysis when no loops are involved
-    xla = xla_cost_analysis(c).get("flops", 0)
+    xla = c.cost_analysis().get("flops", 0)
     assert abs(r["flops"] - xla) / want < 0.01
 
 
@@ -46,7 +47,7 @@ def test_scan_flops_scaled_by_trip_count():
     want = 11 * 2 * 128**3
     assert abs(r["flops"] - want) / want < 0.02
     # and the raw XLA number is ~11x smaller (the bug this parser fixes)
-    xla = xla_cost_analysis(c).get("flops", 0)
+    xla = c.cost_analysis().get("flops", 0)
     assert xla < r["flops"] / 5
 
 
@@ -103,16 +104,15 @@ def test_collectives_in_scan_scaled():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
         from repro.launch.hlo_costs import parse_hlo_costs
 
-        mesh = make_mesh((8,), ("d",))
+        mesh = jax.make_mesh((8,), ("d",))
         def f(x):
             def body(c, _):
                 return jax.lax.psum(c, "d"), None
             out, _ = jax.lax.scan(body, x, None, length=5)
             return out
-        g = shard_map(f, mesh=mesh, in_specs=P(None, None),
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(None, None),
                       out_specs=P(None, None), check_vma=False)
         x = jnp.zeros((64, 256), jnp.float32)
         c = jax.jit(g).lower(x).compile()
@@ -141,3 +141,30 @@ def test_shape_parsing_tuples_and_scalars():
     assert _shape_numel_bytes("bf16[2,3]{1,0}")[1] == 12
     assert _shape_numel_bytes("token[]") == (0, 0)
     assert _shape_numel_bytes("f32[]")[0] == 1
+
+
+def test_device_peaks_known_and_unknown():
+    from repro.launch.hlo_costs import device_peaks
+
+    assert device_peaks("TPU v5 lite")["flops_bf16"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("TPU v99")
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The entry points' cache: JAX_COMPILATION_CACHE_DIR wins untouched,
+    else the fixed <checkout>/.jax_cache."""
+    from repro.common import CHECKOUT, use_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(CHECKOUT / ".jax_cache")
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

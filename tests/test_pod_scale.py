@@ -155,7 +155,8 @@ def test_component_fold_kind_matches_oneshot():
 
 def test_sharded_merge_edges_bit_identical():
     """merge='comp' (sharded O(s/P) label state, c-sized relabel broadcast)
-    produces BIT-IDENTICAL MSTEdges to merge='point' (replicated labels) and
+    produces the same MSTEdges as merge='point' (replicated labels: u, v,
+    valid exact, w to 2 ulp of 1.0) and
     oracle-matching Prim cuts — at s=321 and s=9 (non-shard-multiple: pad
     label -1 must not propagate into any component), on flat (8,),
     pod (2, 4), and non-pow-2 flat (6,) meshes."""
@@ -166,6 +167,7 @@ def test_sharded_merge_edges_bit_identical():
     from repro.distrib.hac_parallel import boruvka_mst_distributed
     from repro.distrib.sharding import make_flat_mesh, make_pod_mesh
 
+    W_ATOL = 2 * np.finfo(np.float32).eps
     meshes = ((make_flat_mesh(8), ("data",)),
               (make_pod_mesh(2, 4), ("pod", "data")),
               (make_flat_mesh(6), ("data",)))
@@ -180,9 +182,13 @@ def test_sharded_merge_edges_bit_identical():
                 mesh, axes, xs, merge="point", compact=False)
             ec = boruvka_mst_distributed(
                 mesh, axes, xs, merge="comp", compact=False)
-            for a, b in ((ec.u, ep.u), (ec.v, ep.v), (ec.w, ep.w),
-                         (ec.valid, ep.valid)):
+            # the two merges sweep through different block shapes, which XLA
+            # may round differently: the tree (u, v, valid) is exact, and w
+            # agrees to 2 ulp of 1.0, the scale of a unit-vector dot
+            for a, b in ((ec.u, ep.u), (ec.v, ep.v), (ec.valid, ep.valid)):
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(
+                np.asarray(ec.w), np.asarray(ep.w), rtol=0, atol=W_ATOL)
             assert int(np.sum(np.asarray(ec.valid))) == s - 1
             got = np.asarray(cut_mst_edges(ec, s, k))
             # labels are canonical min-member ids -> comparable directly
@@ -204,8 +210,9 @@ def test_sharded_merge_edges_bit_identical():
 
 def test_sharded_sweep_edges_bit_identical():
     """The ring-sharded candidate sweep (sweep='sharded': no (s, d) xs
-    broadcast, block copies rotate via per-axis ppermute rings) produces
-    BIT-IDENTICAL MSTEdges to the replicated sweep (sweep='bcast') — on
+    broadcast, block copies rotate via per-axis ppermute rings) produces the
+    same MSTEdges as the replicated sweep (sweep='bcast': u, v, valid exact,
+    w to 2 ulp of 1.0) — on
     1-device, 4-device, non-power-of-two 6-device, and (3, 2) pod meshes,
     at non-shard-multiple s (pad rows ride the ring with label -1), with
     the overlapped exchange schedule both on and off."""
@@ -214,6 +221,7 @@ def test_sharded_sweep_edges_bit_identical():
     from repro.distrib.hac_parallel import boruvka_mst_distributed
     from repro.distrib.sharding import make_flat_mesh, make_pod_mesh
 
+    W_ATOL = 2 * np.finfo(np.float32).eps
     rng = np.random.default_rng(7)
     xs = jnp.asarray(rng.normal(size=(321, 24)).astype(np.float32))
 
@@ -231,9 +239,13 @@ def test_sharded_sweep_edges_bit_identical():
             (make_pod_mesh(3, 2), ("pod", "data"), "pod32")):
         for overlap in (True, False):
             got = edges(mesh, axes, sweep="sharded", overlap=overlap)
-            for a, b in zip(ref, got):
-                np.testing.assert_array_equal(
-                    a, b, err_msg=f"{tag} overlap={overlap}")
+            msg = f"{tag} overlap={overlap}"
+            for i in (0, 1, 3):  # u, v, valid: exact
+                np.testing.assert_array_equal(ref[i], got[i], err_msg=msg)
+            # w: the ring contracts in other block shapes than the
+            # replicated sweep, which XLA may round apart
+            np.testing.assert_allclose(
+                ref[2], got[2], rtol=0, atol=W_ATOL, err_msg=msg)
     print("SHARDED SWEEP OK")
     """, timeout=900)
 
