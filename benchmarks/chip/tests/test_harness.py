@@ -1,0 +1,251 @@
+"""CPU tests of the benchmark harness.
+
+    python -m pytest -q benchmarks/chip/tests
+
+Covered: every cell of BENCHMARK.json resolves to its files; names and units
+keep to the allowed characters; the work formulas match hand counts; the
+trace reductions give known answers on a small recorded trace; a run without
+a TPU exits non-zero; a rehearsal with the timed path broken underneath
+reads a number over its limit; and the control, the reference one precision
+step down in the program's place, reads over a limit.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+HERE = Path(__file__).resolve().parent
+BENCH = HERE.parent
+ROOT = BENCH.parents[1]
+sys.path.insert(0, str(BENCH))
+
+import faults  # noqa: E402
+import harness  # noqa: E402
+import traces  # noqa: E402
+
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+# ------------------------------------------------------------------- the spec
+
+
+def test_top_level_keys():
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert SPEC["paths"] == ["benchmarks/chip"]
+    assert 1 <= SPEC["run_seconds"] <= 51
+    assert len(json.dumps(SPEC)) < 64 * 1024
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_resolves_to_its_files(cell):
+    c = harness.Cell(SPEC, cell)
+    assert c.cfg["n"] > 0 and c.cfg["k"] > 0
+    assert c.traffic["entry"] in faults.FAULTS
+    assert any(m["name"] == c.metric for m in SPEC["end_to_end"])
+    assert c.per_layer, "every cell reports a per-layer metric"
+    for m in c.per_layer:
+        assert (BENCH / "metrics" / f"{m['name']}.py").is_file()
+    for name in ("center_gap", "assign_gap", "rss_gap", "sim_gap"):
+        assert name in c.traffic["limits"]
+
+
+def test_every_name_and_unit_is_allowed():
+    named = SPEC["configs"] + SPEC["workloads"] + SPEC["end_to_end"] + SPEC["per_layer"]
+    names = [x["name"] for x in named]
+    assert len(names) == len(set(names))
+    for n in names:
+        assert NAME.match(n), n
+    for w in SPEC["workloads"]:
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4)
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        assert UNIT.match(m["unit"]), m["unit"]
+        assert m["better"] in ("lower", "higher")
+    for c in SPEC["configs"]:
+        for key in c["reduced"]:
+            assert NAME.match(key)
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert cfg["reduced"] == c["reduced"]
+    layers = {m["layer"] for m in SPEC["per_layer"]}
+    assert all(1 <= len(x) <= 200 for x in layers)
+    e2e = {m["name"] for m in SPEC["end_to_end"]}
+    assert all(m["moves"] in e2e for m in SPEC["per_layer"])
+
+
+def test_roofline_metrics_name_their_kernel():
+    for m in SPEC["per_layer"]:
+        if m["name"].endswith("_roofline"):
+            kernel = m["name"][: -len("_roofline")]
+            assert (BENCH / "work" / f"{kernel}.py").is_file()
+            assert m["unit"] == "%"
+
+
+# -------------------------------------------------------------------- work
+
+
+def test_assign_stats_work_by_hand():
+    work = harness.load_module(BENCH / "work" / "assign_stats.py").work
+    flops, nbytes = work(rows=3, k=2, d=4)
+    # similarities 2*3*2*4 = 48, sums 3*4 = 12, squared norms 2*3*4 = 24
+    assert flops == 84
+    # x 12, centers 8, idx+sim 6, sums 8, counts+min+sumsq 6 values of 4 bytes
+    assert nbytes == 4 * 40
+
+
+def test_sim_best_edge_work_by_hand():
+    work = harness.load_module(BENCH / "work" / "sim_best_edge.py").work
+    flops, nbytes = work(rows=2, cols=3, d=5)
+    assert flops == 2 * 2 * 3 * 5
+    # rows 10 + cols 15 values, labels 5, index + similarity per row 4
+    assert nbytes == 4 * 34
+
+
+# ------------------------------------------------------------------ traces
+
+
+def _trace(ops, window=(0, 100)):
+    return traces.Trace(ops=ops, host=[("PjitFunction(step)", 40, 70)], window=window)
+
+
+def test_union_and_subtract():
+    assert traces.union([(5, 7), (0, 2), (1, 3), (7, 9)]) == [(0, 3), (5, 9)]
+    assert traces.subtract([(0, 10)], [(2, 3), (5, 12)]) == [(0, 2), (3, 5)]
+    assert traces.subtract([(0, 4), (6, 8)], [(3, 7)]) == [(0, 3), (7, 8)]
+
+
+def test_idle_and_roofline_on_known_trace():
+    t = _trace({0: [("assign_stats_pallas.3", 10, 30), ("fusion.1", 20, 40),
+                    ("assign_stats_pallas.3", 60, 80), ("copy", 95, 120)]})
+    # busy: [10, 40) + [60, 80) + [95, 100) = 55 of 100
+    assert traces.busy_share(t) == pytest.approx(0.55)
+    assert traces.kernel_calls(t, "assign_stats_pallas") == [20, 20]
+    ctx = harness.Ctx.__new__(harness.Ctx)
+    ctx.trace, ctx.peaks = t, {"flops_bf16": 1e9, "hbm_bytes_per_s": 1e9}
+    # least time max(10/1e9, 5/1e9) s = 10 ns a call, against 20 ns a call
+    assert ctx.roofline("assign_stats_pallas", 10.0, 5.0) == pytest.approx(50.0)
+    assert ctx.roofline("sim_best_edge_pallas", 10.0, 5.0) is None
+    gaps = traces.breakdown(t)["idle_gaps"]
+    assert gaps[0] == ["PjitFunction(step)", 20e-9]
+
+
+def test_reduction_on_recorded_chip_trace():
+    """300 ms of the traced window of a one-chip paper1gb-buckshot run, as
+    the TPU trace names its operations (names cut to 100 characters).
+    Busy time is checked against a sweep over every event boundary, and the
+    kernels' calls against a count of their HLO names."""
+    rec = json.loads((HERE / "recorded_trace.json").read_text())
+    ops = [tuple(e) for e in rec["ops"]["0"]]
+    t = traces.Trace(ops={0: ops}, host=[tuple(h) for h in rec["host"]],
+                     window=tuple(rec["window"]))
+    lo, hi = t.window
+    cuts = sorted({lo, hi, *(min(max(s, lo), hi) for _, s, e in ops),
+                   *(min(max(e, lo), hi) for _, s, e in ops)})
+    starts = sorted((s, e) for _, s, e in ops)
+    busy = 0
+    for a, b in zip(cuts, cuts[1:]):
+        if any(s <= a and e >= b for s, e in starts if s <= a):
+            busy += b - a
+    assert traces.busy_share(t) == pytest.approx(busy / (hi - lo), rel=1e-12)
+    for kernel in ("assign_stats_pallas", "sim_best_edge_pallas"):
+        pattern = re.compile(rf"^%{kernel}(\.\d+)* = ")
+        want = sum(1 for n, s, e in ops if pattern.match(n) and s >= lo and e <= hi)
+        assert want > 0
+        assert len(traces.kernel_calls(t, kernel)) == want
+    work = harness.load_module(BENCH / "work" / "sim_best_edge.py").work
+    flops, nbytes = work(rows=10_000, cols=10_000, d=2048)
+    ctx = harness.Ctx.__new__(harness.Ctx)
+    ctx.trace, ctx.peaks = t, {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+    calls = traces.kernel_calls(t, "sim_best_edge_pallas")
+    want = 100 * (flops / 197e12) / (sum(calls) / len(calls) / 1e9)
+    assert ctx.roofline("sim_best_edge_pallas", flops, nbytes) == pytest.approx(want)
+    assert 0 < want < 100
+
+
+# ------------------------------------------------------------------- runs
+
+
+def _run(args, env_extra=None, timeout=600):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", **(env_extra or {})}
+    return subprocess.run(
+        [sys.executable, *args], cwd=ROOT, env=env, capture_output=True,
+        text=True, timeout=timeout,
+    )
+
+
+def test_no_tpu_exits_nonzero_and_prints_no_result():
+    r = _run(["benchmarks/chip/run.py", "--workload", CELLS[0], "--seed", "1",
+              "--seconds", "1", "--trace", "0"])
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "no TPU" in r.stderr
+
+
+FAULT_RUNNER = """
+import sys
+sys.path.insert(0, "benchmarks/chip")
+sys.path.insert(0, "src")
+import faults, harness
+fault = {fault!r}
+if fault:
+    faults.plant({entry!r}, fault)
+raise SystemExit(harness.main(sys.argv[1:]))
+"""
+CASES = [(c, f) for c in CELLS
+         for f in [None, *faults.FAULTS[harness.Cell(SPEC, c).traffic["entry"]]]]
+
+
+def _over_limit(cell, fault):
+    entry = harness.Cell(SPEC, cell).traffic["entry"]
+    runner = FAULT_RUNNER.format(fault=fault, entry=entry)
+    r = _run(["-c", runner, "--workload", cell,
+              "--seed", "2147483659", "--seconds", "0.5", "--trace", "0",
+              "--rehearse"], timeout=900)
+    assert r.returncode == 0, r.stderr[-3000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    assert res["correct"] is False  # a rehearsal never is
+    assert set(res["checks"]) == set(harness.Cell(SPEC, cell).traffic["limits"])
+    return res["failed"], [n for n, c in res["checks"].items() if not c["value"] <= c["limit"]]
+
+
+@pytest.mark.parametrize("cell,fault", CASES)
+def test_broken_timed_path_is_not_correct(cell, fault):
+    failed, over = _over_limit(cell, fault)
+    if fault is None:
+        assert failed == 0 and over == [], f"a sound rehearsal read over a limit: {over}"
+    else:
+        assert failed > 0 and over, f"{fault} left every number of {cell} within its limit"
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_reads_over_a_limit(cell):
+    """The reference put in the program's place one precision step down
+    fails on every seed, at a size a test run holds."""
+    r = _run(["benchmarks/chip/control.py", "--workload", cell, "--seeds",
+              "11,12,13", "--side", "control", "--rehearse"], timeout=900)
+    assert r.returncode == 0, r.stderr[-3000:]
+    limits = harness.Cell(SPEC, cell).traffic["limits"]
+    for line in r.stdout.strip().splitlines():
+        got = json.loads(line)
+        assert got["correct"] is False
+        assert any(not v <= limits[n] for n, v in got["numbers"].items()), got
+
+
+def test_seed_beyond_32_bits_gives_distinct_keys():
+    import jax
+
+    a = harness.base_key(2**31 + 5)
+    b = harness.base_key(5)
+    assert not bool((jax.random.key_data(a) == jax.random.key_data(b)).all())
+    assert math.isfinite(float(jax.random.uniform(harness.base_key(2**33 + 1))))
