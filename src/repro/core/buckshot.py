@@ -23,6 +23,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.common import l2_normalize
 from repro.core import sampling
@@ -178,7 +179,8 @@ def buckshot_stream(
     rows, sample_idx = sampling.reservoir_sample_stream(
         stream, s, key, checkpoint=checkpoint, guard=guard
     )
-    labels, init_centers = phase1_from_sample(rows, k, impl=impl, hac=hac)
+    with TraceAnnotation("buckshot.phase1"):
+        labels, init_centers = phase1_from_sample(rows, k, impl=impl, hac=hac)
     km = kmeans_fit_stream(
         stream, init_centers, k, max_iters=kmeans_iters, tol=tol, impl=impl,
         checkpoint=checkpoint.scoped("buckshot") if checkpoint is not None else None,

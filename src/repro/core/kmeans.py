@@ -22,6 +22,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common import l2_normalize
 from repro.core import metrics
@@ -399,7 +400,7 @@ def _stream_pass(
     a pass killed mid-collection resumes with the already-collected prefix
     intact — bit-identical to the uninterrupted run."""
     from repro.resilience import array_token
-    from repro.text.stream import run_pass  # lazy: keeps layering acyclic
+    from repro.text.stream import run_pass, to_device  # lazy: keeps layering acyclic
 
     if bounded:
         drift_dev = (
@@ -408,7 +409,7 @@ def _stream_pass(
 
         def fold(state, ch, ci):
             carry, obj, idxs, sims, blocks, pruned, rows = state
-            x = jnp.asarray(ch.x)
+            x, w = to_device(ch)
             if bounds_blocks is not None and ci < len(bounds_blocks):
                 bi, bl, bh = bounds_blocks[ci]
                 b = ops.Bounds(
@@ -417,15 +418,15 @@ def _stream_pass(
             else:
                 b = ops.bounds_identity(x.shape[0])
             carry, (idx, sim, o, nb, p, r) = _stream_fold_chunk_bounded(
-                carry, x, jnp.asarray(ch.w), centers, b, drift_dev,
-                index=index, impl=impl,
+                carry, x, w, centers, b, drift_dev, index=index, impl=impl,
             )
-            blocks = blocks + [
-                (np.asarray(nb.idx), np.asarray(nb.lo), np.asarray(nb.hi))
-            ]
-            if collect:
-                idxs = idxs + [np.asarray(idx)]
-                sims = sims + [np.asarray(sim)]
+            with TraceAnnotation("stream.sync"):
+                blocks = blocks + [
+                    (np.asarray(nb.idx), np.asarray(nb.lo), np.asarray(nb.hi))
+                ]
+                if collect:
+                    idxs = idxs + [np.asarray(idx)]
+                    sims = sims + [np.asarray(sim)]
             return carry, obj + o, idxs, sims, blocks, pruned + p, rows + r
 
         carry, obj, idxs, sims, blocks, pruned, rows = run_pass(
@@ -442,24 +443,26 @@ def _stream_pass(
             if checkpoint is not None
             else None,
         )
+        with TraceAnnotation("stream.sync"):
+            pruned, rows = float(pruned), float(rows)
         return StreamPassOut(
             stats=carry,
             idx=np.concatenate(idxs)[: stream.n] if collect else None,
             best_sim=np.concatenate(sims)[: stream.n] if collect else None,
             objective=obj,
             bounds=blocks,
-            pruned=float(pruned),
-            rows=float(rows),
+            pruned=pruned,
+            rows=rows,
         )
 
     def fold(state, ch, ci):
         carry, obj, idxs, sims = state
-        carry, (idx, sim, o) = _stream_fold_chunk(
-            carry, jnp.asarray(ch.x), jnp.asarray(ch.w), centers, impl=impl
-        )
+        x, w = to_device(ch)
+        carry, (idx, sim, o) = _stream_fold_chunk(carry, x, w, centers, impl=impl)
         if collect:
-            idxs = idxs + [np.asarray(idx)]
-            sims = sims + [np.asarray(sim)]
+            with TraceAnnotation("stream.sync"):
+                idxs = idxs + [np.asarray(idx)]
+                sims = sims + [np.asarray(sim)]
         return carry, obj + o, idxs, sims
 
     carry, obj, idxs, sims = run_pass(
@@ -547,10 +550,15 @@ def kmeans_fit_stream(
             pass_id=pid, checkpoint=checkpoint, guard=guard,
             bounded=bounded, bounds_blocks=bblocks, drift=_drift(), index=index,
         )
-        sums, counts = out.stats[0], out.stats[1]
-        means = sums / jnp.maximum(counts, 1.0)[:, None]
-        new_centers = jnp.where(counts[:, None] > 0, l2_normalize(means), centers)
-        moved = float(jnp.max(jnp.sum((new_centers - centers) ** 2, axis=1)))
+        with TraceAnnotation("kmeans.update"):
+            sums, counts = out.stats[0], out.stats[1]
+            means = sums / jnp.maximum(counts, 1.0)[:, None]
+            new_centers = jnp.where(
+                counts[:, None] > 0, l2_normalize(means), centers
+            )
+            moved = jnp.max(jnp.sum((new_centers - centers) ** 2, axis=1))
+            with TraceAnnotation("stream.sync"):
+                moved = float(moved)
         if checkpoint is not None:
             checkpoint.save_result(
                 pid,

@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @functools.partial(jax.jit, static_argnames=("n", "s"))
@@ -96,7 +97,7 @@ def reservoir_sample_stream(
     Returns (rows (s, d) device, global indices (s,) np.int32, sorted by
     descending score — a uniformly shuffled order).
     """
-    from repro.text.stream import run_pass  # lazy: keeps layering acyclic
+    from repro.text.stream import run_pass, to_device  # lazy: keeps layering acyclic
 
     if s > stream.n:
         raise ValueError(f"sample size {s} exceeds stream rows {stream.n}")
@@ -111,11 +112,11 @@ def reservoir_sample_stream(
             return jnp.asarray(done["rows"]), np.asarray(done["gidx"])
 
     def fold(carry, ch, ci):
+        x, w = to_device(ch)
         scores, gidx = _chunk_scores(
-            jax.random.fold_in(key, ci), jnp.asarray(ch.w),
-            jnp.int32(ch.start), stream.chunk,
+            jax.random.fold_in(key, ci), w, jnp.int32(ch.start), stream.chunk,
         )
-        return merge_top_s(carry, scores, gidx, jnp.asarray(ch.x), s)
+        return merge_top_s(carry, scores, gidx, x, s)
 
     _, gidx, rows = run_pass(
         stream,
@@ -130,10 +131,10 @@ def reservoir_sample_stream(
         guard=guard,
         meta=meta,
     )
+    with TraceAnnotation("stream.sync"):
+        gidx = np.asarray(gidx)
     if checkpoint is not None:
         checkpoint.save_result(
-            "reservoir",
-            {"rows": np.asarray(rows), "gidx": np.asarray(gidx)},
-            meta=meta,
+            "reservoir", {"rows": np.asarray(rows), "gidx": gidx}, meta=meta
         )
-    return rows, np.asarray(gidx)
+    return rows, gidx

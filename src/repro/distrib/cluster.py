@@ -301,7 +301,7 @@ def _fold_pass(
     all live in the snapshot, and a restored job carry is re-sharded onto
     the mesh by ``FoldJob.carry_device`` — a killed distributed pass resumes
     with every per-shard partial back on its shard."""
-    from repro.text.stream import run_pass  # lazy: keeps layering acyclic
+    from repro.text.stream import run_pass, to_device  # lazy: keeps layering acyclic
 
     meta = None
     if checkpoint is not None:
@@ -316,9 +316,10 @@ def _fold_pass(
 
     def fold(state, ch, ci):
         carry, idxs, bblocks = state
+        x, w = to_device(ch)
         data = {
-            "x": shard_rows(mesh, axes, jnp.asarray(ch.x)),
-            "w": shard_rows(mesh, axes, jnp.asarray(ch.w)),
+            "x": shard_rows(mesh, axes, x),
+            "w": shard_rows(mesh, axes, w),
         }
         if bounded:
             if bounds_blocks is None:
@@ -940,7 +941,7 @@ def reservoir_sample_distributed_stream(
 
     Returns (rows (s, d) replicated, global indices (s,) np.int32), in
     descending-score order — a uniformly shuffled order."""
-    from repro.text.stream import run_pass  # lazy: keeps layering acyclic
+    from repro.text.stream import run_pass, to_device  # lazy: keeps layering acyclic
 
     if s > stream.n:
         raise ValueError(f"sample size {s} exceeds stream rows {stream.n}")
@@ -987,9 +988,10 @@ def reservoir_sample_distributed_stream(
     )
 
     def fold(carry, ch, ci):
+        x, w = to_device(ch)
         data = {
-            "x": shard_rows(mesh, axes, jnp.asarray(ch.x)),
-            "w": shard_rows(mesh, axes, jnp.asarray(ch.w)),
+            "x": shard_rows(mesh, axes, x),
+            "w": shard_rows(mesh, axes, w),
         }
         bcast = {
             "key": jax.random.fold_in(key, ci),

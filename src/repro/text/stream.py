@@ -31,7 +31,10 @@ import queue
 import threading
 from typing import Any, Callable, Iterable, Iterator, NamedTuple, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class StreamChunk(NamedTuple):
@@ -45,6 +48,13 @@ class StreamChunk(NamedTuple):
     x: "np.ndarray"  # (chunk, dim) f32 rows (padding rows all-zero)
     w: "np.ndarray"  # (chunk,) f32, 1.0 real / 0.0 padding
     start: int  # global row index of this chunk's first row
+
+
+def to_device(ch: StreamChunk) -> tuple[jax.Array, jax.Array]:
+    """The chunk's rows and weights on the default device: the one place a
+    fold moves a host chunk to the device, under the ``stream.h2d`` span."""
+    with TraceAnnotation("stream.h2d"):
+        return jnp.asarray(ch.x), jnp.asarray(ch.w)
 
 
 def _pad_block(block: np.ndarray, chunk: int) -> tuple[np.ndarray, np.ndarray]:
@@ -253,6 +263,14 @@ def concat_streams(*streams, chunk: int | None = None) -> "CorpusStream":
 # raises GuardError with pass/chunk attribution the moment NaN/Inf reaches
 # the carry. Deterministic fault injection (repro/testing/faults.py) hooks
 # the producer right where real faults would occur.
+#
+# The executor names its host time in the profiler's trace with fixed-name
+# spans (jax.profiler.TraceAnnotation; about half a microsecond each while no
+# profiler runs): ``stream.pass`` around a pass, ``stream.wait`` around each
+# take from the source on the consumer thread, ``stream.produce`` around each
+# pull from the source on the producer thread (inline when prefetch is off),
+# ``stream.h2d`` in ``to_device``, and ``stream.sync`` where a driver blocks
+# on a device result.
 
 
 class _Raise:
@@ -290,7 +308,7 @@ class _PrefetchIter:
 
     def _produce(self, source: Iterator[Any]) -> None:
         try:
-            for item in source:
+            for item in _produced(source):
                 if not self._put(item):
                     return  # consumer closed the pass
             self._put(_END)
@@ -342,6 +360,23 @@ class _PrefetchIter:
             self.close()
         except Exception:
             pass
+
+
+def _produced(source: Iterator[Any]) -> Iterator[Any]:
+    """``source``'s items, each pulled under a ``stream.produce`` span.
+    Closing this generator closes ``source``."""
+    try:
+        while True:
+            with TraceAnnotation("stream.produce"):
+                try:
+                    item = next(source)
+                except StopIteration:
+                    return
+            yield item
+    finally:
+        close = getattr(source, "close", None)
+        if close is not None:
+            close()
 
 
 def _resolve_prefetch(prefetch: Any) -> int:
@@ -431,9 +466,6 @@ def _chunk_source(
 
 @functools.lru_cache(maxsize=256)
 def _finite_reducer(shape: tuple, dtype: str):
-    import jax
-    import jax.numpy as jnp
-
     del shape, dtype  # cache key only: one compiled reducer per leaf shape
     return jax.jit(lambda a: jnp.all(jnp.isfinite(a)))
 
@@ -447,8 +479,6 @@ def _carry_finite(carry: Any, seen: set | None = None) -> bool:
     per-chunk output blocks accumulate in carry lists but never mutate, so
     re-scanning them every chunk would make the guard O(chunks²) over a pass.
     Device leaves are always re-checked (the running accumulators DO change)."""
-    import jax
-
     for leaf in jax.tree_util.tree_leaves(carry):
         if isinstance(leaf, jax.Array):
             if jnp_issubdtype_inexact(leaf.dtype) and not bool(
@@ -547,42 +577,46 @@ def run_pass(
     depth = _resolve_prefetch(prefetch)
     if wd is not None:
         depth = max(depth, 1)  # the watchdog needs the producer on a thread
-    it: Any = _PrefetchIter(source, depth, timeout=wd) if depth > 0 else source
     expect = start_chunk
     guard_seen: set | None = set() if guard == "finite" else None
-    try:
-        while True:
-            try:
-                item = next(it)
-            except StopIteration:
-                break
-            except queue.Empty:
-                from repro.resilience import StreamTimeout
+    with TraceAnnotation("stream.pass"):
+        it: Any = (
+            _PrefetchIter(source, depth, timeout=wd)
+            if depth > 0
+            else _produced(source)
+        )
+        try:
+            while True:
+                try:
+                    with TraceAnnotation("stream.wait"):
+                        item = next(it)
+                except StopIteration:
+                    break
+                except queue.Empty:
+                    from repro.resilience import StreamTimeout
 
-                raise StreamTimeout(pass_id, expect, wd) from None
-            ci, ch = item
-            carry = fold(carry, ch, ci)
-            if guard == "finite" and not _carry_finite(carry, guard_seen):
-                from repro.resilience import GuardError
+                    raise StreamTimeout(pass_id, expect, wd) from None
+                ci, ch = item
+                carry = fold(carry, ch, ci)
+                if guard == "finite" and not _carry_finite(carry, guard_seen):
+                    from repro.resilience import GuardError
 
-                raise GuardError(pass_id, ci)
-            expect = ci + 1
-            if (
-                checkpoint is not None
-                and expect % checkpoint.every == 0
-                and expect < stream.n_chunks
-            ):
-                checkpoint.save(
-                    pass_id,
-                    chunk=expect,
-                    carry_host=carry_to_host(carry),
-                    fingerprint=fingerprint,
-                    meta=full_meta,
-                )
-    finally:
-        close = getattr(it, "close", None)
-        if close is not None:
-            close()
+                    raise GuardError(pass_id, ci)
+                expect = ci + 1
+                if (
+                    checkpoint is not None
+                    and expect % checkpoint.every == 0
+                    and expect < stream.n_chunks
+                ):
+                    checkpoint.save(
+                        pass_id,
+                        chunk=expect,
+                        carry_host=carry_to_host(carry),
+                        fingerprint=fingerprint,
+                        meta=full_meta,
+                    )
+        finally:
+            it.close()
     if checkpoint is not None:
         checkpoint.delete(pass_id)  # pass completed: snapshot is stale
     return carry

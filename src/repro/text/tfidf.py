@@ -95,13 +95,14 @@ def df_stream(stream, *, checkpoint=None, guard=None) -> tuple[jax.Array, jax.Ar
     shared streaming executor, so chunk generation overlaps the fold.
     Checkpoints under pass id ``tfidf/df``; guard='finite' attributes the
     first non-finite accumulator to its chunk."""
-    from repro.text.stream import run_pass
+    from repro.text.stream import run_pass, to_device
 
     if stream.n == 0:
         raise ValueError("df_stream: empty stream (n == 0 documents)")
 
     def fold(carry, ch, ci):
-        part = _df_map({"counts": jnp.asarray(ch.x), "w": jnp.asarray(ch.w)}, ())
+        x, w = to_device(ch)
+        part = _df_map({"counts": x, "w": w}, ())
         df, n = carry
         return df + part["df"], n + part["n"]
 
@@ -129,7 +130,7 @@ def df_fold_distributed(mesh, axes, stream) -> dict:
     """Distributed pass 1: the engine fold job — every chunk is mapped and
     combined per shard, ONE psum closes the pass (not one per chunk)."""
     from repro.distrib.sharding import check_stream_shardable, shard_rows
-    from repro.text.stream import run_pass
+    from repro.text.stream import run_pass, to_device
 
     check_stream_shardable(stream, mesh, axes)
     job = make_fold_job(
@@ -137,9 +138,10 @@ def df_fold_distributed(mesh, axes, stream) -> dict:
     )
 
     def fold(carry, ch, ci):
+        x, w = to_device(ch)
         data = {
-            "counts": shard_rows(mesh, axes, jnp.asarray(ch.x)),
-            "w": shard_rows(mesh, axes, jnp.asarray(ch.w)),
+            "counts": shard_rows(mesh, axes, x),
+            "w": shard_rows(mesh, axes, w),
         }
         carry, _ = job.step(carry, data, {})
         return carry
