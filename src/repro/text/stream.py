@@ -20,6 +20,13 @@ Core/distrib import the executor lazily inside their pass drivers, so the
 layering stays acyclic. The resident paths are the one-chunk specialization:
 ``CorpusStream.from_array(x)`` yields the whole array as a single chunk, and
 every streaming entry point run on it reproduces the resident oracle.
+
+``from_array`` streams rows that already sit whole in host RAM, and they are
+fixed for the stream's life: its blocks are built once, with the stream —
+full chunks as views of ``x``, the short last chunk padded then — and every
+pass yields those same read-only arrays. Sources that regenerate their rows
+(``from_blocks``, ``from_texts``, ``concat_streams``, ``map``) pad their last
+chunk in every pass, since a source may change between passes.
 """
 
 from __future__ import annotations
@@ -58,13 +65,16 @@ def to_device(ch: StreamChunk) -> tuple[jax.Array, jax.Array]:
 
 
 def _pad_block(block: np.ndarray, chunk: int) -> tuple[np.ndarray, np.ndarray]:
+    """``block`` (at most ``chunk`` rows) zero-padded to ``chunk`` rows, and
+    its weights. A short block is padded under the ``stream.pad`` span."""
     r = block.shape[0]
     w = np.ones((r,), np.float32)
     if r < chunk:
-        block = np.concatenate(
-            [block, np.zeros((chunk - r,) + block.shape[1:], block.dtype)]
-        )
-        w = np.concatenate([w, np.zeros((chunk - r,), np.float32)])
+        with TraceAnnotation("stream.pad"):
+            block = np.concatenate(
+                [block, np.zeros((chunk - r,) + block.shape[1:], block.dtype)]
+            )
+            w = np.concatenate([w, np.zeros((chunk - r,), np.float32)])
     return block, w
 
 
@@ -142,16 +152,31 @@ class CorpusStream:
     def from_array(x, *, chunk: int | None = None) -> "CorpusStream":
         """Resident array -> stream. ``chunk=None`` keeps the whole array as
         ONE chunk — the thin wrapper that makes every resident path a
-        one-chunk specialization of the streaming path."""
+        one-chunk specialization of the streaming path.
+
+        The rows of ``x`` are fixed for the stream's life. The chunks are
+        built once, here: full chunks are views of ``x`` sharing one weight
+        vector of ones, and a short last chunk is padded now, not in every
+        pass. Every pass yields these same arrays, all read-only, so a
+        consumer that writes into a chunk fails instead of corrupting the
+        next pass."""
         x = np.asarray(x, np.float32)
         n, dim = x.shape
-        chunk = n if chunk is None else chunk
-
-        def blocks() -> Iterator[np.ndarray]:
-            for start in range(0, n, chunk):
-                yield x[start : start + chunk]
-
-        return CorpusStream.from_blocks(blocks, n=n, dim=dim, chunk=chunk)
+        chunks: list[StreamChunk] = []
+        # the constructor checks chunk before the loop below steps by it
+        stream = CorpusStream(
+            lambda: iter(chunks), n=n, dim=dim, chunk=n if chunk is None else chunk
+        )
+        c = stream.chunk
+        ones = np.ones((c,), np.float32)
+        for start in range(0, n, c):
+            block, w = x[start : start + c], ones
+            if block.shape[0] < c:
+                block, w = _pad_block(block, c)
+            for a in (block, w):
+                a.setflags(write=False)
+            chunks.append(StreamChunk(x=block, w=w, start=start))
+        return stream
 
     @staticmethod
     def from_texts(
@@ -269,8 +294,9 @@ def concat_streams(*streams, chunk: int | None = None) -> "CorpusStream":
 # profiler runs): ``stream.pass`` around a pass, ``stream.wait`` around each
 # take from the source on the consumer thread, ``stream.produce`` around each
 # pull from the source on the producer thread (inline when prefetch is off),
-# ``stream.h2d`` in ``to_device``, and ``stream.sync`` where a driver blocks
-# on a device result.
+# ``stream.h2d`` in ``to_device``, ``stream.sync`` where a driver blocks on a
+# device result, and ``stream.pad`` where ``_pad_block`` pads a short block
+# (once per ``from_array`` stream, once per pass for the other sources).
 
 
 class _Raise:

@@ -25,21 +25,30 @@ N_CHUNKS = -(-N // CHUNK)
 PASSES = 4  # the reservoir sample, two K-Means iterations, the final pass
 
 
-def _job():
+def _from_blocks(x):
+    """The rows of ``x`` from a source that regenerates its blocks in every
+    pass, as ``from_texts`` does."""
+    return CorpusStream.from_blocks(
+        lambda: (x[s : s + CHUNK] for s in range(0, N, CHUNK)),
+        n=N, dim=x.shape[1], chunk=CHUNK,
+    )
+
+
+def _job(make_stream=lambda x: CorpusStream.from_array(x, chunk=CHUNK)):
     x = np.random.default_rng(0).random((N, 32), np.float32)
     x /= np.linalg.norm(x, axis=1, keepdims=True)
     return buckshot_stream(
-        CorpusStream.from_array(x, chunk=CHUNK), K, jax.random.key(3),
+        make_stream(x), K, jax.random.key(3),
         kmeans_iters=2, impl="xla", bounded=False,
     )
 
 
-def _traced(logdir: str):
+def _traced(logdir: str, **job):
     """The job under the profiler, and each host thread's span names."""
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     with jax.profiler.trace(logdir, profiler_options=opts):
-        result = _job()
+        result = _job(**job)
         jax.block_until_ready(result.kmeans.centers)
     (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
     threads = []
@@ -70,6 +79,15 @@ def traced_inline(tmp_path_factory):
         return _traced(str(tmp_path_factory.mktemp("trace_inline")))
 
 
+@pytest.fixture(scope="module")
+def traced_blocks(tmp_path_factory):
+    with pytest.MonkeyPatch.context() as mp:
+        mp.delenv("REPRO_STREAM_PREFETCH", raising=False)
+        return _traced(
+            str(tmp_path_factory.mktemp("trace_blocks")), make_stream=_from_blocks
+        )
+
+
 def _consumer(threads):
     (line,) = [t for t in threads if any(n == "stream.pass" for n, _, _ in t)]
     return line
@@ -87,6 +105,8 @@ def _consumer(threads):
         ("stream.sync", N_CHUNKS + 3),
         ("buckshot.phase1", 1),
         ("kmeans.update", 2),
+        # the 88-row tail, padded once when the stream is built
+        ("stream.pad", 1),
     ],
 )
 def test_span_count(traced, name, count):
@@ -100,6 +120,31 @@ def test_produce_spans_run_on_the_producer_thread(traced):
     assert not any(n == "stream.produce" for n, _, _ in consumer)
     producers = [t for t in threads if t is not consumer]
     assert {n for t in producers for n, _, _ in t} == {"stream.produce"}
+
+
+def test_from_array_pads_once_when_the_stream_is_built(traced):
+    consumer = _consumer(traced[1])
+    first_pass = min(s for n, s, _ in consumer if n == "stream.pass")
+    (pad,) = [(s, e) for n, s, e in consumer if n == "stream.pad"]
+    assert pad[1] <= first_pass
+
+
+def test_regenerating_source_pads_once_per_pass_on_the_producer(
+    traced, traced_blocks
+):
+    result, threads = traced_blocks
+    consumer = _consumer(threads)
+    assert not any(n == "stream.pad" for n, _, _ in consumer)
+    producers = [t for t in threads if t is not consumer]
+    pads = [(s, e) for t in producers for n, s, e in t if n == "stream.pad"]
+    produces = [(s, e) for t in producers for n, s, e in t if n == "stream.produce"]
+    assert len(pads) == PASSES
+    assert all(any(a <= s and e <= b for a, b in produces) for s, e in pads)
+    want = traced[0]
+    np.testing.assert_array_equal(
+        np.asarray(result.kmeans.centers), np.asarray(want.kmeans.centers)
+    )
+    np.testing.assert_array_equal(result.kmeans.assignment, want.kmeans.assignment)
 
 
 def test_consumer_spans_do_not_overlap(traced):

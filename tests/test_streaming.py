@@ -104,6 +104,66 @@ def test_stream_from_array_one_chunk_wrapper(corpus):
     np.testing.assert_array_equal(st.materialize(), c.counts)
 
 
+# (n, chunk): n a multiple of chunk, n < chunk, a short tail, one chunk
+FROM_ARRAY_CASES = [(512, 128), (100, 128), (600, 128), (600, None)]
+
+
+def _from_array(n, chunk):
+    x = np.random.default_rng(n).random((n, 16), np.float32)
+    return x, CorpusStream.from_array(x, chunk=chunk)
+
+
+@pytest.mark.parametrize("n,chunk", FROM_ARRAY_CASES)
+def test_stream_from_array_chunks_match_per_pass_padding(n, chunk):
+    """Every pass yields the chunks that padding each block in that pass
+    would give, bit for bit: rows, weights and start."""
+    from repro.text.stream import _pad_block
+
+    x, st = _from_array(n, chunk)
+    c = n if chunk is None else chunk
+    assert (st.n, st.dim, st.chunk) == (n, 16, c)
+    for _ in range(3):
+        got = list(st.chunks())
+        assert [ch.start for ch in got] == list(range(0, n, c))
+        for ch in got:
+            want_x, want_w = _pad_block(x[ch.start : ch.start + c], c)
+            for a, b in ((ch.x, want_x), (ch.w, want_w)):
+                assert a.dtype == b.dtype and a.shape == b.shape
+                assert a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("n,chunk", FROM_ARRAY_CASES)
+def test_stream_from_array_tail_built_once_and_read_only(n, chunk):
+    _, st = _from_array(n, chunk)
+    tails = [list(st.chunks())[-1] for _ in range(3)]
+    for ch in tails[1:]:
+        assert ch.x is tails[0].x and ch.w is tails[0].w
+    assert not tails[0].x.flags.writeable and not tails[0].w.flags.writeable
+    with pytest.raises(ValueError, match="read-only"):
+        tails[0].x[0, 0] = 1.0
+
+
+@pytest.mark.parametrize("n,chunk", FROM_ARRAY_CASES)
+def test_stream_from_array_fault_on_tail_leaves_next_pass_clean(n, chunk):
+    from repro.testing import faults
+    from repro.text.stream import run_pass
+
+    x, st = _from_array(n, chunk)
+    last = st.n_chunks - 1
+
+    def tail_rows(acc, ch, ci):
+        return np.asarray(ch.x).copy() if ci == last else acc
+
+    with faults.inject(f"nan@c{last}") as plan:
+        bad = run_pass(st, tail_rows, None)
+        assert plan.fired("nan") == 1
+    assert np.isnan(bad[0]).all()
+    clean = run_pass(st, tail_rows, None)
+    start = last * st.chunk
+    np.testing.assert_array_equal(clean[: n - start], x[start:])
+    assert not clean[n - start :].any()
+
+
 def test_stream_from_blocks_enforces_contract():
     """A short mid-stream block or a row-count mismatch must raise, not
     silently pad the middle of the logical row order."""
