@@ -10,10 +10,10 @@ runs one job through the cell's own entry (the timed path, at the timed
 sizes); on the ``control`` side it puts the reference in the program's
 place, one precision step down (float32 matrix products as three bfloat16
 passes, the TPU's ``BF16_BF16_F32_X3``, where the configuration states
-``highest``). ``--fault`` plants one of ``faults.py``'s faults under the
-program first. Each job's answers go through the harness's own check, with
-the cell's limits, and one JSON line per seed and side gives its numbers,
-diagnostics and verdict. A limit lies above every program reading and below
+``highest``). ``--fault`` plants one of the faults that the cell's entry
+lists (``entries/<entry>.py``) under the program first. Each job's answers
+go through the harness's own check, with the cell's limits, and one JSON
+line per seed and side gives its numbers, diagnostics and verdict. A limit lies above every program reading and below
 the control's. The benchmark's own runs never run the control or a fault.
 ``--rehearse`` runs tiny on the CPU.
 """
@@ -64,7 +64,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True, help="comma-separated whole numbers")
     ap.add_argument("--side", choices=("program", "control", "both"), default="both")
-    ap.add_argument("--fault", help="a fault of faults.py, planted under the program")
+    ap.add_argument("--fault", help="a fault of the cell's entry, planted under the program")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     spec = json.loads((harness.ROOT / "BENCHMARK.json").read_text())

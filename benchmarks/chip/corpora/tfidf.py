@@ -1,4 +1,5 @@
-"""The benchmark's corpus, made on the device from a seed.
+"""A tf-idf corpus, made on the device from a seed: the generator that a
+configuration names as ``"generator": "tfidf"`` in its ``corpus`` block.
 
 The distribution is that of the program's host generator (``text/synth``):
 each of ``topics`` topics is a Dirichlet(``sharpness``) distribution over
@@ -90,7 +91,7 @@ def lmax_for(doc_len: float) -> int:
     return int(math.ceil(doc_len + 10.0 * math.sqrt(doc_len)))
 
 
-def tfidf_corpus(key, corpus: dict, n: int, d: int):
+def make(key, corpus: dict, n: int, d: int):
     """(n, d) f32 tf-idf rows on the default device. ``corpus`` holds
     topics, doc_len, sharpness and background."""
     kmix, krows = jax.random.split(key)

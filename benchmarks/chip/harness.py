@@ -1,23 +1,35 @@
 """One run of one cell: set-up, the measured window, the checks, the line.
 
-Everything that belongs to one configuration, traffic mix, per-layer metric
-or kernel sits in a file of its own, found by its name in ``BENCHMARK.json``:
+Every part of a cell is a file of its own, found by its name, so that a new
+deployment is added as files alone and no file here names one:
 
-- ``configs/<config>.json``: the deployment (sizes, corpus, precision);
-- ``traffic/<mix>.json``: which entry of the program a job calls, with its
-  parameters, the end-to-end metric its jobs report, and the limit of each
-  number that the comparison with the reference reads;
+- ``BENCHMARK.json`` names the cell's configuration and traffic mix;
+- ``configs/<config>.json``: the deployment (sizes, precision, and a
+  ``corpus`` block that names its ``generator``);
+- ``corpora/<generator>.py``: ``make(key, corpus, n, d)`` gives the corpus
+  on the device from the seed;
+- ``traffic/<mix>.json``: the ``entry`` of the program that a job calls and
+  the ``kind`` of reference that checks it, with their parameters, the
+  end-to-end metric its jobs report, and the limit of each number that the
+  comparison with the reference reads;
+- ``entries/<entry>.py``: ``make(cell, corpus, impl, devices)`` gives
+  ``run(key)``, one timed job, which returns its answers by name;
+  ``HOST_CORPUS`` says whether it takes the corpus in host memory; and
+  ``FAULTS`` names the faults valid for its jobs (``faults.py``);
+- ``kinds/<kind>.py``: the plain reference of such a job and the numbers
+  that compare a job's answers with it (``reference.py``);
 - ``metrics/<metric>.py``: ``read(ctx)`` gives the metric from the trace, or
   None where the trace holds nothing for it;
 - ``work/<kernel>.py``: ``work(**shape)`` gives (FLOPs, bytes) that the
   kernel's call needs, from its logical shape.
 
-The entries below are the one general generator: a traffic file names one
-of them and gives its parameters.
+Configurations, corpora, entries and kinds may be shared by several cells:
+a cell is one pair of configuration and traffic mix.
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import math
@@ -58,12 +70,12 @@ class Cell:
         ]
 
     def params(self) -> dict:
-        """What the reference needs to know of one job."""
-        t, c = self.traffic, self.cfg
-        return {
-            "n": c["n"], "k": c["k"], "s": c.get("sample_size"),
-            "iters": t["iters"], "sample": t.get("sample"), "chunk": t.get("chunk"),
-        }
+        """What the reference needs to know of one job: the configuration's
+        keys and the traffic's, which win on a clash, with ``s`` for the
+        sample size."""
+        cfg = {n: v for n, v in self.cfg.items() if n != "rehearse"}
+        traffic = {n: v for n, v in self.traffic.items() if n != "rehearse"}
+        return {**cfg, "s": self.cfg.get("sample_size"), **traffic}
 
 
 def load_module(path: Path):
@@ -81,66 +93,6 @@ def base_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
 
 
-# ------------------------------------------------------------------ entries
-
-
-def entry_kmeans(cell, x, impl):
-    import jax
-
-    from repro.core import kmeans
-
-    k, t = cell.cfg["k"], cell.traffic
-    # tol 0: every job runs its max_iters iterations unless it converges exactly
-    fn = jax.jit(lambda x, key: kmeans(x, k, key, max_iters=t["iters"], tol=0.0, **impl))
-
-    def run(key):
-        r = jax.block_until_ready(fn(x, key))
-        return {"centers": r.centers, "assignment": r.assignment, "rss": r.rss,
-                "best_sim": r.best_sim}
-
-    return run
-
-
-def entry_buckshot(cell, x, impl):
-    import jax
-
-    from repro.core import buckshot
-
-    k, t = cell.cfg["k"], cell.traffic
-    fn = jax.jit(lambda x, key: buckshot(x, k, key, kmeans_iters=t["iters"], **impl))
-
-    def run(key):
-        r = jax.block_until_ready(fn(x, key))
-        return {
-            "centers": r.kmeans.centers, "assignment": r.kmeans.assignment,
-            "rss": r.kmeans.rss, "best_sim": r.kmeans.best_sim, "sample_idx": r.sample_idx,
-            "sample_labels": r.sample_labels, "init_centers": r.init_centers,
-        }
-
-    return run
-
-
-def entry_buckshot_stream(cell, x_host, impl):
-    import jax
-
-    from repro.core import buckshot_stream
-    from repro.text.stream import CorpusStream
-
-    k, t = cell.cfg["k"], cell.traffic
-    stream = CorpusStream.from_array(x_host, chunk=t["chunk"])
-
-    def run(key):
-        r = buckshot_stream(stream, k, key, kmeans_iters=t["iters"], **impl)
-        km = jax.block_until_ready(r.kmeans)
-        return {
-            "centers": km.centers, "assignment": km.assignment, "rss": km.rss,
-            "best_sim": km.best_sim, "sample_idx": r.sample_idx, "sample_labels": r.sample_labels,
-            "init_centers": r.init_centers,
-        }
-
-    return run
-
-
 # ------------------------------------------------------------------- set-up
 
 
@@ -150,30 +102,23 @@ class Setup:
     def __init__(self, cell: Cell, key, rehearse: bool):
         import jax
 
-        import corpus
-
         cfg, t = cell.cfg, cell.traffic
         impl = {"impl": "pallas_interpret"} if rehearse else {}
         self.kind = t["kind"]
+        corpus = importlib.import_module(f"corpora.{cfg['corpus']['generator']}")
+        entry = importlib.import_module(f"entries.{t['entry']}")
         t0 = time.perf_counter()
-        self.x = jax.block_until_ready(
-            corpus.tfidf_corpus(key, cfg["corpus"], cfg["n"], cfg["d"])
-        )
+        self.x = jax.block_until_ready(corpus.make(key, cfg["corpus"], cfg["n"], cfg["d"]))
         self.corpus_s = time.perf_counter() - t0
         self.x_host = None
-        if t["entry"] == "kmeans":
-            self.run = entry_kmeans(cell, self.x, impl)
-        elif t["entry"] == "buckshot":
-            self.run = entry_buckshot(cell, self.x, impl)
-        elif t["entry"] == "buckshot_stream":
+        if entry.HOST_CORPUS:
             t0 = time.perf_counter()
             self.x_host = jax.device_get(self.x)
             self.x.delete()
             self.x = None
             self.to_host_s = time.perf_counter() - t0
-            self.run = entry_buckshot_stream(cell, self.x_host, impl)
-        else:
-            raise ValueError(f"unknown entry {t['entry']!r}")
+        devices = jax.devices()[: cell.chips]
+        self.run = entry.make(cell, self.x_host if entry.HOST_CORPUS else self.x, impl, devices)
 
     def data(self):
         """The corpus on the device, for the reference."""
@@ -322,16 +267,21 @@ class Ctx:
     def work(self, kernel: str):
         return load_module(HERE / "work" / f"{kernel}.py").work
 
-    def roofline(self, kernel: str, flops: float, nbytes: float):
+    def roofline(self, kernel: str, works):
         """Least time over device time, in %, over the kernel's calls in the
-        window, each needing ``flops`` and ``nbytes``."""
+        window. ``works`` lists the (FLOPs, bytes) of one job's calls of the
+        kernel in order, call i needing ``works[i % len(works)]``: None where
+        the window's calls are not whole cycles of it."""
         import traces as tr
 
         calls = tr.kernel_calls(self.trace, kernel)
-        if not calls or self.peaks is None:
+        if not calls or self.peaks is None or len(calls) % len(works):
             return None
-        least = max(flops / self.peaks["flops_bf16"], nbytes / self.peaks["hbm_bytes_per_s"])
-        return 100.0 * len(calls) * least * 1e9 / sum(calls)
+        least = sum(
+            max(f / self.peaks["flops_bf16"], b / self.peaks["hbm_bytes_per_s"])
+            for f, b in works
+        )
+        return 100.0 * (len(calls) // len(works)) * least * 1e9 / sum(calls)
 
 
 def use_compile_cache() -> None:

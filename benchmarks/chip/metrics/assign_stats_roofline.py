@@ -5,5 +5,5 @@ centers."""
 
 def read(ctx):
     c = ctx.cfg
-    flops, nbytes = ctx.work("assign_stats")(rows=c["n"], k=c["k"], d=c["d"])
-    return ctx.roofline("assign_stats_pallas", flops, nbytes)
+    work = ctx.work("assign_stats")(rows=c["n"], k=c["k"], d=c["d"])
+    return ctx.roofline("assign_stats_pallas", [work])

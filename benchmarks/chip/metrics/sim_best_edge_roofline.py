@@ -6,5 +6,5 @@ against itself, s rows by s columns."""
 def read(ctx):
     c = ctx.cfg
     s = c["sample_size"]
-    flops, nbytes = ctx.work("sim_best_edge")(rows=s, cols=s, d=c["d"])
-    return ctx.roofline("sim_best_edge_pallas", flops, nbytes)
+    work = ctx.work("sim_best_edge")(rows=s, cols=s, d=c["d"])
+    return ctx.roofline("sim_best_edge_pallas", [work])

@@ -1,9 +1,12 @@
 """The plain reference and the comparison that decides ``correct``.
 
 Spherical K-Means, single-link HAC (Prim's maximum spanning tree, cut at k
-clusters) and Buckshot, written in straightforward ``jax.numpy``. Nothing
-here imports the program or takes anything it made: the data are the
-benchmark's own (``corpus.py``), samples and initial centers are drawn from
+clusters) and the samples, written in straightforward ``jax.numpy``. Each
+kind of job that a traffic file names is a file ``kinds/<kind>.py`` built
+from these pieces: ``run(x, key, p, *, lower=False)`` does the whole job
+and ``numbers(x, key, out, p)`` compares a job's answers. Nothing here or
+there imports the program or takes anything it made: the data are the
+benchmark's own (``corpora/``), samples and initial centers are drawn from
 the job's key by the same published rules, and the program's outputs enter
 only as the answers being checked.
 
@@ -13,8 +16,9 @@ bfloat16 passes (hi·hi + hi·lo + lo·hi, float32 accumulation), the TPU's
 ``BF16_BF16_F32_X3`` algorithm: that is the control, the reference put in
 the program's place one precision step down.
 
-The numbers (a traffic file gives a limit to each one its cell compares;
-the others are printed beside them):
+The numbers of a job's final K-Means answers (a traffic file gives a limit
+to each one its cell compares; the others are printed beside them; a kind
+adds its own):
 
 - ``assign_gap``: over every row, how far the similarity of the center the
   program assigned lies below the best similarity to the program's own final
@@ -34,26 +38,16 @@ the others are printed beside them):
   sound run, and a fault that breaks a few tiles of centers moves more.
 - ``clusters_disagree`` (printed, not compared): how many clusters the two
   final assignments fill with different rows.
-- ``cut_margin`` (printed, not compared): the weakest kept tree edge less
-  the strongest cut one, how near the cut is to a tie.
 - ``sim_gap`` (where the program returns each row's best similarity): the
   largest gap between that similarity and the one recomputed here for the
   center the program assigned. It reads the precision of the assignment
   product itself, near ties or not.
-- ``sample_mismatch`` (Buckshot, where the program returns its sample):
-  sampled rows that differ from the reference's draw. Exact.
-- ``cut_gap`` (Buckshot): the largest similarity between two sample rows
-  that the program put in different clusters, less the weight of the
-  strongest spanning-tree edge that single-link cuts. Single-link at k
-  clusters is the k-partition of least such similarity, so a correct cut
-  reads 0, a near tie reads rounding, and a wrong cut reads a gap.
-- ``init_center_gap`` (Buckshot): largest coordinate gap between the
-  program's initial centers and the unit means of its own sample clusters.
 """
 
 from __future__ import annotations
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -248,25 +242,21 @@ def label_centers(xs, labels, *, k, lower=False):
 # ---------------------------------------------------------------- the jobs
 
 
-def _init_kmeans(x, key, k):
-    return l2n(x[sample_choice(key, n=x.shape[0], s=k)])
+def load_kind(kind: str):
+    """The reference of one kind of job, ``kinds/<kind>.py``."""
+    return importlib.import_module(f"kinds.{kind}")
 
 
 def run_reference(kind, x, key, p, *, lower=False):
     """The whole job, done by the reference: the control when ``lower``."""
-    k = p["k"]
-    out = {}
-    if kind == "buckshot":
-        idx = draw_sample(key, p)
-        xs = l2n(x[idx])
-        labels = single_link(xs, k=k, lower=lower)
-        init = label_centers(xs, labels, k=k, lower=lower)
-        out.update(sample_idx=idx, sample_labels=labels, init_centers=init)
-    else:
-        init = _init_kmeans(x, key, k)
-    c, a, best, rss = kmeans_fit(x, init, k=k, max_iters=p["iters"], lower=lower)
-    out.update(centers=c, assignment=a, rss=rss, best_sim=best)
-    return out
+    return load_kind(kind).run(x, key, p, lower=lower)
+
+
+def kmeans_job(x, init, p, *, lower=False):
+    """K-Means from ``init`` for ``p["iters"]`` iterations, with the answers
+    named as a job returns them."""
+    c, a, best, rss = kmeans_fit(x, init, k=p["k"], max_iters=p["iters"], lower=lower)
+    return {"centers": c, "assignment": a, "rss": rss, "best_sim": best}
 
 
 @jax.jit
@@ -294,27 +284,11 @@ def _center_numbers(c_prog, c_ref, a_prog, a_ref):
     return per, touched
 
 
-def numbers(kind, x, key, out, p) -> dict[str, float]:
-    """The numbers of one job's answers ``out``."""
+def kmeans_numbers(x, init, out, p) -> dict[str, float]:
+    """The numbers of a job's K-Means answers in ``out``, against the
+    reference's K-Means from ``init``."""
     k = p["k"]
     got: dict[str, float] = {}
-    if kind == "buckshot":
-        idx = draw_sample(key, p)
-        xs = l2n(x[idx])
-        got["sample_mismatch"] = float(
-            np.sum(np.asarray(out["sample_idx"]) != np.asarray(idx))
-        )
-        lp = jnp.asarray(np.asarray(out["sample_labels"], np.int32))
-        labels, cut_gap, margin = phase1_check(xs, lp, k=k)
-        got["cut_gap"] = float(cut_gap)
-        got["cut_margin"] = float(margin)
-        own = label_centers(xs, lp, k=k)
-        got["init_center_gap"] = float(
-            np.max(np.abs(np.asarray(out["init_centers"]) - np.asarray(own)))
-        )
-        init = label_centers(xs, labels, k=k)
-    else:
-        init = _init_kmeans(x, key, k)
     c_ref, a_ref, _, _ = kmeans_fit(x, init, k=k, max_iters=p["iters"])
     c_prog = jnp.asarray(np.asarray(out["centers"]))
     assignment = jnp.asarray(np.asarray(out["assignment"]), jnp.int32)
@@ -331,3 +305,8 @@ def numbers(kind, x, key, out, p) -> dict[str, float]:
     got["rss_gap"] = float(rss_gap)
     got["sim_gap"] = float(sim_gap)
     return got
+
+
+def numbers(kind, x, key, out, p) -> dict[str, float]:
+    """The numbers of one job's answers ``out``."""
+    return load_kind(kind).numbers(x, key, out, p)

@@ -2,20 +2,25 @@
 
     python -m pytest -q benchmarks/chip/tests
 
-Covered: every cell of BENCHMARK.json resolves to its files; names and units
-keep to the allowed characters; the work formulas match hand counts; the
-trace reductions give known answers on a small recorded trace; a run without
-a TPU exits non-zero; a rehearsal with the timed path broken underneath
-reads a number over its limit; and the control, the reference one precision
-step down in the program's place, reads over a limit.
+Covered: every cell of BENCHMARK.json resolves to its files, and every
+fault its entry names can be planted; a cell added as new files alone runs;
+names and units keep to the allowed characters; the work formulas match hand
+counts; the trace reductions and the roofline of one or of a cycle of call
+shapes give known answers on a small recorded trace; a run without a TPU
+exits non-zero; a rehearsal with the timed path broken underneath reads a
+number over its limit; and the control, the reference one precision step
+down in the program's place, reads over a limit.
 """
 
 from __future__ import annotations
 
+import filecmp
+import importlib
 import json
 import math
 import os
 import re
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -27,7 +32,6 @@ BENCH = HERE.parent
 ROOT = BENCH.parents[1]
 sys.path.insert(0, str(BENCH))
 
-import faults  # noqa: E402
 import harness  # noqa: E402
 import traces  # noqa: E402
 
@@ -35,6 +39,11 @@ SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def _entry(cell):
+    return importlib.import_module(f"entries.{harness.Cell(SPEC, cell).traffic['entry']}")
+
 
 # ------------------------------------------------------------------- the spec
 
@@ -51,7 +60,15 @@ def test_top_level_keys():
 def test_cell_resolves_to_its_files(cell):
     c = harness.Cell(SPEC, cell)
     assert c.cfg["n"] > 0 and c.cfg["k"] > 0
-    assert c.traffic["entry"] in faults.FAULTS
+    assert (BENCH / "entries" / f"{c.traffic['entry']}.py").is_file()
+    assert (BENCH / "kinds" / f"{c.traffic['kind']}.py").is_file()
+    assert (BENCH / "corpora" / f"{c.cfg['corpus']['generator']}.py").is_file()
+    entry = _entry(cell)
+    assert isinstance(entry.HOST_CORPUS, bool) and callable(entry.make)
+    assert entry.FAULTS, "every entry names the faults valid for its jobs"
+    # each planter replaces its function of the program in a process of its own
+    r = _run(["-c", PLANT, c.traffic["entry"], *entry.FAULTS])
+    assert r.returncode == 0, r.stderr[-3000:]
     assert any(m["name"] == c.metric for m in SPEC["end_to_end"])
     assert c.per_layer, "every cell reports a per-layer metric"
     for m in c.per_layer:
@@ -119,6 +136,18 @@ def _trace(ops, window=(0, 100)):
     return traces.Trace(ops=ops, host=[("PjitFunction(step)", 40, 70)], window=window)
 
 
+def _recorded_trace():
+    rec = json.loads((HERE / "recorded_trace.json").read_text())
+    return traces.Trace(ops={0: [tuple(e) for e in rec["ops"]["0"]]},
+                        host=[tuple(h) for h in rec["host"]], window=tuple(rec["window"]))
+
+
+def _ctx(trace, flops_per_s, bytes_per_s):
+    ctx = harness.Ctx.__new__(harness.Ctx)
+    ctx.trace, ctx.peaks = trace, {"flops_bf16": flops_per_s, "hbm_bytes_per_s": bytes_per_s}
+    return ctx
+
+
 def test_union_and_subtract():
     assert traces.union([(5, 7), (0, 2), (1, 3), (7, 9)]) == [(0, 3), (5, 9)]
     assert traces.subtract([(0, 10)], [(2, 3), (5, 12)]) == [(0, 2), (3, 5)]
@@ -131,11 +160,10 @@ def test_idle_and_roofline_on_known_trace():
     # busy: [10, 40) + [60, 80) + [95, 100) = 55 of 100
     assert traces.busy_share(t) == pytest.approx(0.55)
     assert traces.kernel_calls(t, "assign_stats_pallas") == [20, 20]
-    ctx = harness.Ctx.__new__(harness.Ctx)
-    ctx.trace, ctx.peaks = t, {"flops_bf16": 1e9, "hbm_bytes_per_s": 1e9}
+    ctx = _ctx(t, 1e9, 1e9)
     # least time max(10/1e9, 5/1e9) s = 10 ns a call, against 20 ns a call
-    assert ctx.roofline("assign_stats_pallas", 10.0, 5.0) == pytest.approx(50.0)
-    assert ctx.roofline("sim_best_edge_pallas", 10.0, 5.0) is None
+    assert ctx.roofline("assign_stats_pallas", [(10.0, 5.0)]) == pytest.approx(50.0)
+    assert ctx.roofline("sim_best_edge_pallas", [(10.0, 5.0)]) is None
     gaps = traces.breakdown(t)["idle_gaps"]
     assert gaps[0] == ["PjitFunction(step)", 20e-9]
 
@@ -145,10 +173,8 @@ def test_reduction_on_recorded_chip_trace():
     the TPU trace names its operations (names cut to 100 characters).
     Busy time is checked against a sweep over every event boundary, and the
     kernels' calls against a count of their HLO names."""
-    rec = json.loads((HERE / "recorded_trace.json").read_text())
-    ops = [tuple(e) for e in rec["ops"]["0"]]
-    t = traces.Trace(ops={0: ops}, host=[tuple(h) for h in rec["host"]],
-                     window=tuple(rec["window"]))
+    t = _recorded_trace()
+    ops = t.ops[0]
     lo, hi = t.window
     cuts = sorted({lo, hi, *(min(max(s, lo), hi) for _, s, e in ops),
                    *(min(max(e, lo), hi) for _, s, e in ops)})
@@ -165,12 +191,45 @@ def test_reduction_on_recorded_chip_trace():
         assert len(traces.kernel_calls(t, kernel)) == want
     work = harness.load_module(BENCH / "work" / "sim_best_edge.py").work
     flops, nbytes = work(rows=10_000, cols=10_000, d=2048)
-    ctx = harness.Ctx.__new__(harness.Ctx)
-    ctx.trace, ctx.peaks = t, {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+    ctx = _ctx(t, 197e12, 819e9)
     calls = traces.kernel_calls(t, "sim_best_edge_pallas")
     want = 100 * (flops / 197e12) / (sum(calls) / len(calls) / 1e9)
-    assert ctx.roofline("sim_best_edge_pallas", flops, nbytes) == pytest.approx(want)
+    assert ctx.roofline("sim_best_edge_pallas", [(flops, nbytes)]) == pytest.approx(want)
     assert 0 < want < 100
+
+
+@pytest.mark.parametrize("kernel,work,shape", [
+    ("assign_stats_pallas", "assign_stats", {"rows": 250_000, "k": 400, "d": 2048}),
+    ("sim_best_edge_pallas", "sim_best_edge", {"rows": 10_000, "cols": 10_000, "d": 2048}),
+])
+def test_roofline_of_one_work_reads_as_every_call_alike(kernel, work, shape):
+    """A cycle of one work reads, to the last digit, what the share read when
+    every call of the kernel was given the same work."""
+    flops, nbytes = harness.load_module(BENCH / "work" / f"{work}.py").work(**shape)
+    ctx = _ctx(_recorded_trace(), 197e12, 819e9)
+    calls = traces.kernel_calls(ctx.trace, kernel)
+    least = max(flops / 197e12, nbytes / 819e9)
+    assert ctx.roofline(kernel, [(flops, nbytes)]) == 100.0 * len(calls) * least * 1e9 / sum(calls)
+
+
+def test_roofline_of_a_two_shape_cycle_by_hand():
+    """Calls alternate between 800 centers and 400, as a BKC job's do."""
+    t = _trace({0: [("assign_stats_pallas.1", 0, 40), ("assign_stats_pallas.2", 40, 60),
+                    ("assign_stats_pallas.1", 60, 100), ("assign_stats_pallas.2", 100, 120)]},
+               window=(0, 120))
+    ctx = _ctx(t, 1e9, 1e9)
+    # least times: max(30, 6) = 30 ns and max(10, 12) = 12 ns, over 2 cycles
+    # against 40 + 20 + 40 + 20 = 120 ns of device time
+    share = ctx.roofline("assign_stats_pallas", [(30.0, 6.0), (10.0, 12.0)])
+    assert share == pytest.approx(100.0 * 2 * (30 + 12) / 120)
+
+
+@pytest.mark.parametrize("calls,cycle", [(3, 2), (4, 3), (1, 2)])
+def test_roofline_of_a_partial_cycle_reads_none(calls, cycle):
+    t = _trace({0: [("assign_stats_pallas", 10 * i, 10 * i + 5) for i in range(calls)]})
+    ctx = _ctx(t, 1e9, 1e9)
+    assert ctx.roofline("assign_stats_pallas", [(1.0, 1.0)] * cycle) is None
+    assert ctx.roofline("assign_stats_pallas", [(1.0, 1.0)]) is not None
 
 
 # ------------------------------------------------------------------- runs
@@ -192,6 +251,13 @@ def test_no_tpu_exits_nonzero_and_prints_no_result():
     assert "no TPU" in r.stderr
 
 
+PLANT = """
+import sys
+sys.path[:0] = ["benchmarks/chip", "src"]
+import faults
+for name in sys.argv[2:]:
+    faults.plant(sys.argv[1], name)
+"""
 FAULT_RUNNER = """
 import sys
 sys.path.insert(0, "benchmarks/chip")
@@ -202,8 +268,7 @@ if fault:
     faults.plant({entry!r}, fault)
 raise SystemExit(harness.main(sys.argv[1:]))
 """
-CASES = [(c, f) for c in CELLS
-         for f in [None, *faults.FAULTS[harness.Cell(SPEC, c).traffic["entry"]]]]
+CASES = [(c, f) for c in CELLS for f in [None, *_entry(c).FAULTS]]
 
 
 def _over_limit(cell, fault):
@@ -249,3 +314,79 @@ def test_seed_beyond_32_bits_gives_distinct_keys():
     b = harness.base_key(5)
     assert not bool((jax.random.key_data(a) == jax.random.key_data(b)).all())
     assert math.isfinite(float(jax.random.uniform(harness.base_key(2**33 + 1))))
+
+
+TOY_ENTRY = '''"""One K-Means iteration a job, under names of its own."""
+
+import faults
+
+HOST_CORPUS = False
+
+
+def make(cell, x, impl, devices):
+    import jax
+
+    from repro.core import kmeans
+
+    k, iters = cell.cfg["k"], cell.traffic["iters"]
+    fn = jax.jit(lambda x, key: kmeans(x, k, key, max_iters=iters, tol=0.0, **impl))
+
+    def run(key):
+        r = jax.block_until_ready(fn(x, key))
+        return {"centers": r.centers, "assignment": r.assignment, "rss": r.rss,
+                "best_sim": r.best_sim}
+
+    return run
+
+
+FAULTS = {"step_unchanged": faults.step_unchanged}
+'''
+TOY_KIND = '''"""Checked as a K-Means job is."""
+
+from kinds.kmeans import numbers, run  # noqa: F401
+'''
+
+
+def test_cell_added_as_files_alone_runs(tmp_path):
+    """A copy of the benchmark takes a new cell as new files and one entry of
+    ``workloads``: an entry, a kind and a traffic mix of its own. Its
+    rehearsal reads every check within its limit, and no file that was
+    there before differs afterwards."""
+    bench = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("__pycache__"))
+    limits = json.loads((BENCH / "traffic" / "kmeans-resident.json").read_text())["limits"]
+    added = {
+        "entries/toy_one_step.py": TOY_ENTRY,
+        "kinds/toy_one_step.py": TOY_KIND,
+        "traffic/toy-one-step.json": json.dumps(
+            {"entry": "toy_one_step", "kind": "toy_one_step", "iters": 1,
+             "metric": "job_s", "limits": limits}),
+    }
+    for rel, text in added.items():
+        assert not (bench / rel).exists()
+        (bench / rel).write_text(text)
+    toy = {"name": "toy-one-step", "config": SPEC["configs"][0]["name"],
+           "traffic": "toy-one-step", "chips": 1, "why": "one K-Means iteration a job"}
+    spec = {**SPEC, "workloads": [*SPEC["workloads"], toy]}
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT / "src")}
+    r = subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", "--workload", "toy-one-step",
+         "--seed", "2147483659", "--seconds", "0.5", "--trace", "0", "--rehearse"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    assert res["rehearsal"] is True and res["failed"] == 0
+    assert set(res["metrics"]) == {"setup_s", "job_s"}
+    assert set(res["checks"]) == set(limits)
+    assert all(c["value"] <= c["limit"] for c in res["checks"].values()), res["checks"]
+
+    def files(top):
+        return {str(f.relative_to(top)) for f in top.rglob("*")
+                if f.is_file() and "__pycache__" not in f.parts}
+
+    assert files(bench) == files(BENCH) | set(added)
+    for rel in files(BENCH):
+        assert filecmp.cmp(BENCH / rel, bench / rel, shallow=False), rel
