@@ -25,16 +25,30 @@ deployment is added as files alone and no file here names one:
 
 Configurations, corpora, entries and kinds may be shared by several cells:
 a cell is one pair of configuration and traffic mix.
+
+Every window keeps a stall record (``Stalls``), on stderr only: per job the
+process's CPU seconds, context switches, page faults and seconds of garbage
+collection, and a watchdog that dumps every Python thread's stack while a
+job over its threshold (3 x a warm job, at least 0.5 s) is still stuck. Each
+such job leaves one ``stall`` line, with the device's allocator and the
+host's load and pressure read as it ends; the ``window`` lines give how many
+there were and their excess over the median job. Nothing of it reaches the
+result line or its metrics.
 """
 
 from __future__ import annotations
 
+import faulthandler
+import gc
 import importlib
 import importlib.util
 import json
 import math
 import os
+import resource
 import shutil
+import signal
+import statistics
 import sys
 import tempfile
 import time
@@ -47,6 +61,7 @@ ROOT = HERE.parents[1]
 # slows the host, so the per-layer metrics read a window of their own
 TRACE_SECONDS = 8.0
 CHECKED_JOBS = 2  # jobs of the window, drawn from the seed, that are checked
+STALL_MIN_S = 0.5  # the watchdog's threshold is 3 x a warm job, never under this
 
 
 class Cell:
@@ -185,33 +200,182 @@ class JobKeys:
         return jax.random.fold_in(self.base, j)
 
 
-def window(run, keys, seconds: float, rng):
+class Stalls:
+    """The stall record of one window, on stderr only: nothing of it reaches
+    the result line or its metrics.
+
+    Around every job, at two system calls a job: ``getrusage(RUSAGE_SELF)``
+    as it ends, whose difference from the reading before it (the end of the
+    job before, or the window's start) gives the job's user and system CPU
+    seconds, voluntary and involuntary context switches, and minor and major
+    page faults; the seconds spent in garbage collection, by generation,
+    from a ``gc.callbacks`` hook that the window registers once; and a
+    watchdog, the process's real-time interval timer with ``faulthandler``
+    registered on its SIGALRM. The timer is set to ``threshold`` as each
+    job starts, which also cancels the job before's, and is cancelled when
+    the window closes. A job that passes ``threshold`` seconds (with the
+    window's few microseconds of bookkeeping after it) dumps every Python
+    thread's stack to stderr while it is still stuck, and once it ends
+    leaves one line::
+
+        stall j= job_s= threshold_s= gc_s= gc_s_by_gen= utime_s= stime_s=
+          nvcsw= nivcsw= minflt= majflt= hbm_in_use= num_allocs=
+          largest_free= loadavg= psi=
+
+    with the device's ``memory_stats()`` and the host's ``/proc/loadavg``
+    and ``/proc/pressure/{cpu,io,memory}`` (``some``/``full`` as
+    avg10/total microseconds) read as it ends; what cannot be read is
+    ``none``. ``gc_s`` keeps each job's seconds of collection.
+    """
+
+    def __init__(self, threshold: float, device=None):
+        self.threshold = threshold
+        self.device = device
+        self.gc_s: list[float] = []
+        self.records: list[dict] = []
+        self._gc_by_gen = [0.0, 0.0, 0.0]
+        self._gc_began = 0.0
+
+    def _gc_clock(self, phase, info):
+        if phase == "start":
+            self._gc_began = time.perf_counter()
+        else:
+            self._gc_by_gen[info["generation"]] += time.perf_counter() - self._gc_began
+
+    def open(self):
+        gc.callbacks.append(self._gc_clock)
+        faulthandler.register(signal.SIGALRM, file=sys.stderr.fileno(), all_threads=True)
+        self._gc_open = [s["collections"] for s in gc.get_stats()]
+        self._ru_open = self._ru = resource.getrusage(resource.RUSAGE_SELF)
+
+    def close(self):
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.unregister(signal.SIGALRM)
+        gc.callbacks.remove(self._gc_clock)
+        self._ru_close = resource.getrusage(resource.RUSAGE_SELF)
+        self._gc_close = [s["collections"] for s in gc.get_stats()]
+
+    def arm(self):
+        self._gc0, self._gc1, self._gc2 = self._gc_by_gen
+        signal.setitimer(signal.ITIMER_REAL, self.threshold)
+
+    def ended(self, j: int, job_s: float):
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        g0, g1, g2 = self._gc_by_gen
+        self.gc_s.append((g0 - self._gc0) + (g1 - self._gc1) + (g2 - self._gc2))
+        if job_s > self.threshold:
+            self._stall(j, job_s, (g0 - self._gc0, g1 - self._gc1, g2 - self._gc2), ru)
+        self._ru = ru
+
+    def _stall(self, j, job_s, gc_by_gen, ru):
+        rec = {"j": j, "job_s": job_s, "threshold_s": self.threshold,
+               "gc_s": sum(gc_by_gen), "gc_s_by_gen": ",".join(map(repr, gc_by_gen)),
+               **_rusage_delta(self._ru, ru)}
+        stats = {}
+        if self.device is not None:
+            stats = self.device.memory_stats() or {}
+        rec["hbm_in_use"] = stats.get("bytes_in_use")
+        rec["num_allocs"] = stats.get("num_allocs")
+        rec["largest_free"] = stats.get("largest_free_block_bytes")
+        rec["loadavg"] = _loadavg()
+        rec["psi"] = _pressure()
+        self.records.append(rec)
+        say("stall " + _fields(rec))
+
+    def summary(self, times) -> str:
+        """How many jobs stalled and their excess over the median job."""
+        median = statistics.median(times)
+        excess = sum(r["job_s"] - median for r in self.records)
+        return (f"stalls={len(self.records)} stall_excess_s={excess!r}"
+                f" stall_threshold_s={self.threshold!r}")
+
+    def totals(self) -> str:
+        """The window's whole: seconds of collection, in jobs and between
+        them, collections by generation, and the getrusage deltas."""
+        runs = [b - a for a, b in zip(self._gc_open, self._gc_close)]
+        return _fields({"gc_s": sum(self._gc_by_gen), "gc_collections": ",".join(map(str, runs)),
+                        **_rusage_delta(self._ru_open, self._ru_close)})
+
+
+def _rusage_delta(a, b) -> dict:
+    return {"utime_s": b.ru_utime - a.ru_utime, "stime_s": b.ru_stime - a.ru_stime,
+            "nvcsw": b.ru_nvcsw - a.ru_nvcsw, "nivcsw": b.ru_nivcsw - a.ru_nivcsw,
+            "minflt": b.ru_minflt - a.ru_minflt, "majflt": b.ru_majflt - a.ru_majflt}
+
+
+def _fields(rec: dict) -> str:
+    def text(v):
+        return "none" if v is None else v if isinstance(v, str) else repr(v)
+
+    return " ".join(f"{n}={text(v)}" for n, v in rec.items())
+
+
+def _loadavg() -> str | None:
+    try:
+        return ",".join(Path("/proc/loadavg").read_text().split()[:4])
+    except OSError:
+        return None
+
+
+def _pressure() -> str | None:
+    """``cpu.some=<avg10>/<total us>;io.some=...;io.full=...`` from
+    ``/proc/pressure``, read only."""
+    parts = []
+    for res in ("cpu", "io", "memory"):
+        try:
+            lines = Path(f"/proc/pressure/{res}").read_text().splitlines()
+        except OSError:
+            continue
+        for line in lines:
+            kind, *kv = line.split()
+            v = dict(x.split("=", 1) for x in kv)
+            parts.append(f"{res}.{kind}={v.get('avg10')}/{v.get('total')}")
+    return ";".join(parts) or None
+
+
+def window(run, keys, seconds: float, rng, stalls: Stalls):
     """Jobs back to back until ``seconds`` have passed. Keeps the answers of
     CHECKED_JOBS jobs drawn uniformly by ``rng`` (a reservoir), and each
-    job's time."""
+    job's time; ``elapsed / jobs`` is the cell's metric.
+
+    Every job runs under the probes of ``stalls``: a job over its threshold
+    dumps every Python thread's stack to stderr while it is still stuck and
+    leaves one ``stall`` line there once it ends. The record goes to stderr
+    only; the job times and the answers are what they were without it."""
     kept: list = []
     times: list = []
     j = 0
-    t0 = time.perf_counter()
-    while True:
-        t_job = time.perf_counter()
-        out = run(keys(j))
-        times.append(time.perf_counter() - t_job)
-        if len(kept) < CHECKED_JOBS:
-            kept.append((j, out))
-        else:
-            r = int(rng.integers(0, j + 1))
-            if r < CHECKED_JOBS:
-                kept[r] = (j, out)
-        j += 1
-        if time.perf_counter() - t0 >= seconds:
-            break
-    return time.perf_counter() - t0, j, kept, times
+    stalls.open()
+    try:
+        t0 = time.perf_counter()
+        while True:
+            stalls.arm()
+            t_job = time.perf_counter()
+            out = run(keys(j))
+            job_s = time.perf_counter() - t_job
+            stalls.ended(j, job_s)
+            times.append(job_s)
+            if len(kept) < CHECKED_JOBS:
+                kept.append((j, out))
+            else:
+                r = int(rng.integers(0, j + 1))
+                if r < CHECKED_JOBS:
+                    kept[r] = (j, out)
+            j += 1
+            if time.perf_counter() - t0 >= seconds:
+                break
+        elapsed = time.perf_counter() - t0
+    finally:
+        stalls.close()
+    return elapsed, j, kept, times
 
 
 def job_spread(times) -> str:
     """Per-job times on stderr, to tell a window that is slow in every job
-    from one slowed by a few stalls."""
+    from one slowed by a few stalls. The stall record beside it (``Stalls``:
+    the ``stall`` lines, and ``stalls=``, ``stall_excess_s=`` and the
+    window's totals on the ``window`` lines) says what each job over the
+    threshold was doing; it too goes to stderr only."""
     import numpy as np
 
     t = np.asarray(times)
@@ -317,12 +481,20 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, rehearse: bool
     keys = JobKeys(key)
     say(f"setup corpus_s={setup.corpus_s!r}"
         + (f" to_host_s={setup.to_host_s!r}" if setup.x_host is not None else ""))
+    warm_key = JobKeys(jax.random.fold_in(key, 2), 1)(0)
     t0 = time.perf_counter()
-    setup.run(JobKeys(jax.random.fold_in(key, 2), 1)(0))  # one warm-up job
-    say(f"setup warmup_s={time.perf_counter() - t0!r} cache_hits={counter.hits}"
+    setup.run(warm_key)  # one warm-up job: compiles, or loads from the cache
+    warmup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    setup.run(warm_key)  # and again, warm: the watchdog's yardstick
+    warm_job_s = time.perf_counter() - t0
+    say(f"setup warmup_s={warmup_s!r} warm_job_s={warm_job_s!r} cache_hits={counter.hits}"
         f" cache_misses={counter.misses}")
     setup_s = time.perf_counter() - t_start
+    say(f"setup gc_objects={len(gc.get_objects())} gc_frozen={gc.get_freeze_count()}"
+        f" gc_counts={','.join(map(str, gc.get_count()))}")
     rng = np.random.default_rng(seed)
+    stalls = Stalls(max(STALL_MIN_S, 3 * warm_job_s), devices[0])
 
     logdir = None
     cpu0 = time.process_time()
@@ -335,17 +507,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, rehearse: bool
         try:
             with jax.profiler.TraceAnnotation(tr.WINDOW):
                 elapsed, jobs, kept, times = window(
-                    setup.run, keys, min(seconds, TRACE_SECONDS), rng
+                    setup.run, keys, min(seconds, TRACE_SECONDS), rng, stalls
                 )
         finally:
             jax.profiler.stop_trace()
     else:
-        elapsed, jobs, kept, times = window(setup.run, keys, seconds, rng)
+        elapsed, jobs, kept, times = window(setup.run, keys, seconds, rng, stalls)
     counter.on = False
     cpu_s = time.process_time() - cpu0
     say(f"window jobs={jobs} seconds={elapsed!r} compiles_in_window={counter.count}"
+        f" {stalls.summary(times)}"
         + (f" compiled={','.join(sorted(set(counter.names)))}" if counter.names else ""))
     say(f"window {job_spread(times)} cpu_s={cpu_s!r}")
+    say(f"window {stalls.totals()}")
     device = device_info(devices)
 
     result: dict = {"correct": False, "attempted": jobs, "failed": 0}

@@ -6,8 +6,10 @@ Covered: every cell of BENCHMARK.json resolves to its files, and every
 fault its entry names can be planted; a cell added as new files alone runs;
 names and units keep to the allowed characters; the work formulas match hand
 counts; the trace reductions and the roofline of one or of a cycle of call
-shapes give known answers on a small recorded trace; a run without a TPU
-exits non-zero; a rehearsal with the timed path broken underneath reads a
+shapes give known answers on a small recorded trace; the stall record
+catches a job over its threshold with its stack, counts a collection in its
+own job alone, and leaves the window's job times as they were; a run
+without a TPU exits non-zero; a rehearsal with the timed path broken underneath reads a
 number over its limit; and the control, the reference one precision step
 down in the program's place, reads over a limit.
 """
@@ -15,12 +17,14 @@ down in the program's place, reads over a limit.
 from __future__ import annotations
 
 import filecmp
+import gc
 import importlib
 import json
 import math
 import os
 import re
 import shutil
+import statistics
 import subprocess
 import sys
 from pathlib import Path
@@ -232,6 +236,149 @@ def test_roofline_of_a_partial_cycle_reads_none(calls, cycle):
     assert ctx.roofline("assign_stats_pallas", [(1.0, 1.0)]) is not None
 
 
+# ------------------------------------------------------------------ stalls
+
+
+def _stuck_in_a_job(seconds):
+    import time
+
+    time.sleep(seconds)
+
+
+def _sleepy_run(stall_at=None, job=0.01, stall=0.4):
+    """A job of ``job`` seconds, and one of ``stall`` at job ``stall_at``."""
+    count = [0]
+
+    def run(key):
+        _stuck_in_a_job(stall if count[0] == stall_at else job)
+        count[0] += 1
+        return {"j": count[0] - 1}
+
+    return run
+
+
+def _keys(j):
+    return j
+
+
+def test_one_stall_line_with_its_job_and_a_stack(capfd):
+    import numpy as np
+
+    stalls = harness.Stalls(0.15)
+    _, jobs, _, times = harness.window(_sleepy_run(stall_at=2), _keys, 0.5,
+                                       np.random.default_rng(0), stalls)
+    err = capfd.readouterr().err
+    lines = [x for x in err.splitlines() if x.startswith("stall ")]
+    assert len(lines) == 1, err
+    assert lines[0].startswith("stall j=2 job_s=")
+    for field in ("gc_s=", "utime_s=", "stime_s=", "nivcsw=", "majflt=", "hbm_in_use=none",
+                  "largest_free=none", "loadavg=", "psi="):
+        assert f" {field}" in lines[0], field
+    # the watchdog's dump, written while the job was still asleep, before its line
+    assert "most recent call first" in err
+    assert err.index("in _stuck_in_a_job") < err.index("stall j=2 ")
+    assert [r["j"] for r in stalls.records] == [2] and jobs > 3
+    summary = dict(f.split("=") for f in stalls.summary(times).split())
+    assert summary["stalls"] == "1"
+    assert float(summary["stall_excess_s"]) == times[2] - statistics.median(times)
+
+
+def test_jobs_under_the_threshold_leave_no_stall(capfd):
+    import numpy as np
+
+    stalls = harness.Stalls(0.15)
+    _, jobs, _, times = harness.window(_sleepy_run(), _keys, 0.3,
+                                       np.random.default_rng(0), stalls)
+    err = capfd.readouterr().err
+    assert jobs > 5 and max(times) < 0.15
+    assert "stall" not in err and "most recent call first" not in err
+    assert stalls.records == [] and stalls.summary(times).startswith("stalls=0 stall_excess_s=0")
+    # the watchdog is disarmed and the hook gone once the window has closed
+    assert stalls._gc_clock not in gc.callbacks
+    assert signal_timer_left() == 0.0
+
+
+def signal_timer_left():
+    import signal
+
+    return signal.getitimer(signal.ITIMER_REAL)[0]
+
+
+def test_forced_collection_counts_in_its_job_alone():
+    import numpy as np
+
+    def run(key):
+        if key == 3:
+            for _ in range(20_000):  # cycles that only a collection frees
+                a = []
+                a.append(a)
+            gc.collect()
+        return key
+
+    was = gc.isenabled()
+    gc.disable()  # no collection but the forced one
+    try:
+        stalls = harness.Stalls(10.0)
+        _, jobs, _, _ = harness.window(run, _keys, 0.05, np.random.default_rng(0), stalls)
+    finally:
+        if was:
+            gc.enable()
+    assert jobs > 4 and len(stalls.gc_s) == jobs
+    assert stalls.gc_s[3] > 0
+    assert all(s == 0.0 for j, s in enumerate(stalls.gc_s) if j != 3)
+    totals = dict(f.split("=") for f in stalls.totals().split())
+    assert float(totals["gc_s"]) == stalls.gc_s[3]
+    assert totals["gc_collections"] == "0,0,1"
+
+
+def _window_without_probes(run, keys, seconds, rng):
+    """The window as it was before the stall record, verbatim."""
+    import time
+
+    kept: list = []
+    times: list = []
+    j = 0
+    t0 = time.perf_counter()
+    while True:
+        t_job = time.perf_counter()
+        out = run(keys(j))
+        times.append(time.perf_counter() - t_job)
+        if len(kept) < harness.CHECKED_JOBS:
+            kept.append((j, out))
+        else:
+            r = int(rng.integers(0, j + 1))
+            if r < harness.CHECKED_JOBS:
+                kept[r] = (j, out)
+        j += 1
+        if time.perf_counter() - t0 >= seconds:
+            break
+    return time.perf_counter() - t0, j, kept, times
+
+
+def test_window_times_and_job_s_unchanged_by_the_probes(monkeypatch):
+    """On a clock that moves only inside a job, the window reads the same
+    elapsed time, jobs, kept answers and job times as it did before the
+    stall record, and elapsed / jobs is the job's time."""
+    import numpy as np
+
+    clock = [0.0]
+    monkeypatch.setattr(harness.time, "perf_counter", lambda: clock[0])
+
+    def run(key):
+        clock[0] += 0.01
+        return key
+
+    clock[0] = 0.0
+    before = _window_without_probes(run, _keys, 0.095, np.random.default_rng(7))
+    clock[0] = 0.0
+    stalls = harness.Stalls(0.5)
+    assert harness.window(run, _keys, 0.095, np.random.default_rng(7), stalls) == before
+    elapsed, jobs, _, times = before
+    assert jobs == 10 and times == pytest.approx([0.01] * 10)
+    assert elapsed / jobs == pytest.approx(0.01)
+    assert stalls.records == [] and len(stalls.gc_s) == jobs
+
+
 # ------------------------------------------------------------------- runs
 
 
@@ -379,6 +526,11 @@ def test_cell_added_as_files_alone_runs(tmp_path):
     assert r.returncode == 0, r.stderr[-3000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
     assert res["rehearsal"] is True and res["failed"] == 0
+    # the stall record is on stderr, and nothing of it in the result line
+    assert re.search(r"^window jobs=\d+ .*compiles_in_window=0 stalls=\d+ stall_excess_s=",
+                     r.stderr, re.M), r.stderr[-3000:]
+    assert re.search(r"^window gc_s=\S+ gc_collections=\d+,\d+,\d+ utime_s=", r.stderr, re.M)
+    assert "stall" not in r.stdout
     assert set(res["metrics"]) == {"setup_s", "job_s"}
     assert set(res["checks"]) == set(limits)
     assert all(c["value"] <= c["limit"] for c in res["checks"].values()), res["checks"]
