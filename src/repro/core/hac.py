@@ -448,13 +448,18 @@ def cut_mst_edges(edges: MSTEdges, n: int, k: int) -> jax.Array:
 
     Keeps the n-k strongest valid edges (the k-1 weakest merges are undone),
     then labels connected components — dense ids in [0, k).
+
+    Only the first n-k slots of the stable strongest-first order can be
+    kept, so the labelling runs over those slots alone, not over every slot
+    of the (rounds·s) edge history. Invalid slots sort last and stay masked
+    where they still fall among them.
     """
     neg = float(jnp.finfo(jnp.float32).min)
     w = jnp.where(edges.valid, edges.w, neg)
-    order = jnp.argsort(-w)
-    rank = jnp.argsort(order)
-    keep = jnp.logical_and(edges.valid, rank < (n - k))
-    labels = components_from_edges(n, edges.u, edges.v, keep)
+    top = jnp.argsort(-w)[: max(n - k, 0)]
+    labels = components_from_edges(
+        n, edges.u[top], edges.v[top], edges.valid[top]
+    )
     is_root = labels == jnp.arange(n, dtype=labels.dtype)
     return (jnp.cumsum(is_root.astype(jnp.int32)) - 1)[labels]
 

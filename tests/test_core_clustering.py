@@ -328,6 +328,99 @@ def test_boruvka_row_chunking_is_transparent(rng):
     assert (want == got).all()
 
 
+def _cut_full_width(edges, n, k):
+    """The cut as it labelled every edge slot: mask by rank, then propagate
+    over all L slots. The reference for the kept-edges-only cut."""
+    from repro.core.hac import components_from_edges
+
+    neg = float(jnp.finfo(jnp.float32).min)
+    w = jnp.where(edges.valid, edges.w, neg)
+    order = jnp.argsort(-w)
+    rank = jnp.argsort(order)
+    keep = jnp.logical_and(edges.valid, rank < (n - k))
+    labels = components_from_edges(n, edges.u, edges.v, keep)
+    is_root = labels == jnp.arange(n, dtype=labels.dtype)
+    return (jnp.cumsum(is_root.astype(jnp.int32)) - 1)[labels]
+
+
+def _cut_edges(layout, s):
+    """An edge set of each layout the cut is handed, over s points."""
+    from repro.core.hac import MSTEdges, boruvka_mst as core_boruvka
+
+    r = np.random.default_rng(s)
+    x = r.normal(size=(s, 12)).astype(np.float32)
+    if layout == "tied":  # duplicate rows: many edges of weight exactly 1
+        x = np.repeat(x[: s // 3 + 1], 3, axis=0)[:s]
+    xs = l2_normalize(jnp.asarray(x))
+    if layout == "compact":
+        from repro.distrib.hac_parallel import boruvka_mst_distributed
+        from repro.distrib.sharding import make_flat_mesh
+
+        edges = boruvka_mst_distributed(
+            make_flat_mesh(1), ("data",), xs, merge="comp", compact=True,
+            prewarm=False)
+        assert edges.u.shape[0] < 3 * s  # one slot per component per round
+        return edges
+    edges = core_boruvka(xs)
+    if layout == "forest":  # drop every third tree edge: a forest
+        valid = np.asarray(edges.valid).copy()
+        valid[np.flatnonzero(valid)[::3]] = False
+        edges = MSTEdges(edges.u, edges.v, edges.w, jnp.asarray(valid))
+    return edges
+
+
+@pytest.mark.parametrize("k", ["1", "2", "n-1", "n"])
+@pytest.mark.parametrize("layout", ["rounds", "compact", "tied", "forest"])
+def test_cut_over_kept_edges_matches_full_width(layout, k):
+    """Labelling only the n-k strongest slots gives the labels of masking
+    every slot of the edge history by rank — on Borůvka's (rounds·s) slots,
+    the compact component layout, tied weights and a forest."""
+    from repro.core.hac import cut_mst_edges
+
+    s = 97
+    k = {"1": 1, "2": 2, "n-1": s - 1, "n": s}[k]
+    edges = _cut_edges(layout, s)
+    valid = int(np.asarray(edges.valid).sum())
+    assert valid == (s - 1 - len(range(0, s - 1, 3)) if layout == "forest"
+                     else s - 1)
+    want = np.asarray(_cut_full_width(edges, s, k))
+    got = np.asarray(cut_mst_edges(edges, s, k))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_cut_loop_runs_over_kept_edges_only():
+    """The cut's label-propagation loop carries n-k edge slots, never the
+    L = rounds·s slots of Borůvka's edge history."""
+    from repro.core.hac import MSTEdges, _rounds_for, cut_mst_edges
+
+    s, k = 1000, 20
+    big = _rounds_for(s) * s
+    edges = MSTEdges(
+        jax.ShapeDtypeStruct((big,), jnp.int32),
+        jax.ShapeDtypeStruct((big,), jnp.int32),
+        jax.ShapeDtypeStruct((big,), jnp.float32),
+        jax.ShapeDtypeStruct((big,), jnp.bool_),
+    )
+    jaxpr = jax.make_jaxpr(lambda e: cut_mst_edges(e, s, k))(edges)
+
+    def whiles(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "while":
+                yield eqn
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from whiles(sub)
+
+    loops = list(whiles(jaxpr.jaxpr))
+    assert len(loops) == 1
+    lengths = [v.aval.shape[0] for v in loops[0].invars if v.aval.shape]
+    assert big not in lengths
+    assert lengths.count(s - k) == 3  # u, v and the kept mask
+    assert set(lengths) == {s, s - k}
+
+
 def test_boruvka_emits_spanning_forest(rng):
     s = 128
     xs = l2_normalize(jnp.asarray(rng.normal(size=(s, 8)).astype(np.float32)))
