@@ -25,10 +25,10 @@ def main() -> None:
     ap.add_argument("--hac", choices=["replicated", "boruvka"], default="replicated")
     args = ap.parse_args()
     # NOTE: timings include one-time XLA job compilation (the analogue of
-    # Hadoop's per-job setup). The steady-state comparison — where BKC and
-    # Buckshot win by the paper's 75-85% — is benchmarks/run.py, which times
-    # warm jitted calls. --hac boruvka demonstrates the sharded PARABLE-style
-    # HAC (log(s) extra job rounds; wins only at much larger sample sizes).
+    # Hadoop's per-job setup). Warm jobs are timed on the chip by
+    # benchmarks/chip/run.py. --hac boruvka demonstrates the sharded
+    # PARABLE-style HAC (log(s) extra job rounds; wins only at much larger
+    # sample sizes).
 
     os.environ.setdefault(
         "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}"

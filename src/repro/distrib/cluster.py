@@ -902,8 +902,7 @@ def reservoir_finalize_bytes(
     gathered whole (every device must rank identically), then the s winning
     payload rows — (d,) f32 row + i32 gidx each — move once from their owner
     shards. Legacy whole-payload gather: all P per-shard top-s candidate
-    sets crossed the wire, rows included, before ranking. The gate in
-    tools/bench_diff.py holds the bench-recorded value on this model:
+    sets crossed the wire, rows included, before ranking:
     O(P·s + s·d) vs O(P·s·d)."""
     score_bytes = n_shards * s * 4
     if owner_scatter:

@@ -20,7 +20,7 @@ nested per-axis ppermute rings, so no (s, d) broadcast ever lands anywhere —
 per-device point memory is O(s/P·d + c·d), with c the halving component cap.
 ``sweep='bcast'`` keeps the replicated-columns sweep (s = sqrt(kn) is small
 next to the collection, but its (s, d) broadcast is the first thing to hit a
-per-device memory wall — benchmarks/run.py phase1_sharded). Per round:
+per-device memory wall). Per round:
 
   map     : per-row best cross-component edge on the local rows
             (kernels.ops.sim_best_edge — fused sim build+mask+rowmax+argmax);
@@ -52,11 +52,9 @@ rounds, so rounds keep streaming to the device without a per-round host
 round-trip; a late exit is bounded at check_every - 1 no-op rounds and the
 executed round count is deterministic.
 
-``pre_reduce=False`` keeps the legacy per-row gather path for benchmarking
-the shuffle win (benchmarks/run.py phase1_distributed rows), and
+``pre_reduce=False`` keeps the legacy per-row gather path, and
 ``synthetic_merge_rounds`` isolates the merge subsystem at sample sizes
-where the replicated point-level path exceeds any fixed memory budget
-(benchmarks/run.py phase1_merge rows).
+where the replicated point-level path exceeds any fixed memory budget.
 
 The replicated sample is PADDED to a shard multiple (paper-default s rarely
 divides a 3-device mesh): pad rows carry label -1, which the edge-search
@@ -639,8 +637,7 @@ def bcast_bytes_per_round(
     sweep='bcast': every round lands the full (s, d) f32 xs, the (s,) i32
     comp labels, and the (cap,) i32 comp_to_root on ALL n_shards devices —
     n_shards·(s·d·4 + s·4 + cap·4) bytes per round, CONSTANT in r up to the
-    shrinking cap term. This is the O(s·d) replication wall the phase1_sharded
-    bench drives into an rlimit.
+    shrinking cap term. This is the O(s·d) replication wall.
 
     sweep='sharded': xs never replicates (blocks rotate peer-to-peer — that
     traffic is the ring's shuffle, not broadcast); the only replicated
@@ -719,8 +716,7 @@ def boruvka_mst_distributed(
         sweep='bcast' (same similarity bits, same (w desc, col asc) tie
         order — tests/test_pod_scale.py).
       'bcast': the replicated sweep — the full (s, d) xs broadcast lands
-        on every device each round. Kept for parity tests and as the
-        memory-wall twin in benchmarks (phase1_sharded rows).
+        on every device each round. Kept for parity tests.
     overlap (sharded sweep only): dispatch the NEXT block's ring exchange
     before folding the current block — the §11 double-buffered prefetch
     discipline applied to collectives, so the ppermute hop hides behind
@@ -989,7 +985,7 @@ def _boruvka_rounds(
         # trade is DETERMINISTIC: a late exit costs at most check_every - 1
         # no-op rounds (cheap merges — evalid stays False — but full candidate
         # sweeps), and the executed round count never depends on dispatch
-        # timing, so bench-recorded rounds/shuffle bytes are reproducible.
+        # timing.
         if (r + 1) % check_every == 0 or r == rounds - 1:
             if bool(done):
                 break
@@ -1062,8 +1058,7 @@ def synthetic_merge_rounds(
       merge='point': the replicated `_merge_round_pre` twin — (s,) labels
         plus O(s) scatter/propagation per round, and an (s,)-slot edge
         history growing by 13·s bytes per round. At s = 4M that history
-        alone is ~1.2 GB, which is what the bench's memory budget shows
-        failing (benchmarks/run.py phase1_merge rows).
+        alone is ~1.2 GB, past any fixed per-device memory budget.
 
     Both paths see identical candidates, so at sizes where both run the
     expanded edges match bit-for-bit (tests/test_pod_scale.py).

@@ -632,32 +632,3 @@ def component_best_edge(
         comp, num_segments=c,
     )
     return best_w, best_row, jnp.where(best_j == ref.BIG_I, -1, best_j)
-
-
-# ---------------------------------------------------------------- flash decode
-
-
-@functools.partial(jax.jit, static_argnames=("impl",))
-def flash_decode(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    length: jax.Array,
-    *,
-    impl: str = "auto",
-) -> jax.Array:
-    """One-token GQA attention vs KV cache with online softmax over KV tiles."""
-    impl = _resolve(impl)
-    if impl == "xla":
-        return ref.flash_decode(q, k, v, length)
-
-    def pallas():
-        from repro.kernels import flash_decode as kmod
-
-        return kmod.flash_decode_pallas(
-            q, k, v, length, interpret=impl == "pallas_interpret"
-        )
-
-    return _pallas_guard(
-        "flash_decode", pallas, lambda: ref.flash_decode(q, k, v, length)
-    )

@@ -2,9 +2,8 @@
 
 Each function here is the semantic ground truth. Kernel implementations in
 ``assign_argmax.py`` / ``assign_stats.py`` / ``best_edge.py`` /
-``sim_best_edge.py`` / ``component_reduce.py`` / ``flash_decode.py`` are
-validated against these in interpret mode across shape/dtype sweeps
-(tests/test_kernels.py).
+``sim_best_edge.py`` / ``component_reduce.py`` are validated against
+these in interpret mode across shape/dtype sweeps (tests/test_kernels.py).
 """
 
 from __future__ import annotations
@@ -517,31 +516,3 @@ def component_best_edge(
         row_j[order].astype(jnp.int32), mode="drop"
     )
     return best_w, best_row, best_j
-
-
-def flash_decode(
-    q: jax.Array, k: jax.Array, v: jax.Array, length: jax.Array | int
-) -> jax.Array:
-    """One-token attention against a (possibly padded) KV cache.
-
-    Args:
-      q: (h, dh) query for the new token (h query heads).
-      k: (s, hk, dh) key cache.
-      v: (s, hk, dh) value cache.
-      length: valid prefix length (positions >= length are masked).
-
-    Returns:
-      o: (h, dh) attention output. GQA: query head i reads kv head i // (h//hk).
-    """
-    s, hk, dh = k.shape
-    h = q.shape[0]
-    group = h // hk
-    kq = jnp.repeat(k, group, axis=1)  # (s, h, dh)
-    vq = jnp.repeat(v, group, axis=1)
-    logits = jnp.einsum(
-        "hd,shd->hs", q.astype(jnp.float32), kq.astype(jnp.float32)
-    ) / jnp.sqrt(jnp.float32(dh))
-    mask = jnp.arange(s)[None, :] < length
-    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-    w = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("hs,shd->hd", w, vq.astype(jnp.float32)).astype(q.dtype)

@@ -25,7 +25,6 @@ from repro.kernels.assign_stats import (
 )
 from repro.kernels.best_edge import best_edge_pallas
 from repro.kernels.component_reduce import component_best_edge_pallas
-from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.sim_best_edge import sim_best_edge_pallas
 
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -517,39 +516,6 @@ def test_assign_stats_chunked_equals_oneshot_d_tiled(rng):
             )
 
 
-# ------------------------------------------------------------ flash_decode
-
-
-@pytest.mark.parametrize("s,hk,g,dh,length", [
-    (64, 1, 1, 16, 64), (300, 2, 4, 64, 123), (1024, 4, 2, 128, 1),
-    (513, 2, 6, 32, 257),
-])
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_flash_decode_sweep(rng, s, hk, g, dh, length, dtype):
-    h = hk * g
-    q = _rand(rng, (h, dh), dtype)
-    k = _rand(rng, (s, hk, dh), dtype)
-    v = _rand(rng, (s, hk, dh), dtype)
-    ro = ref.flash_decode(q, k, v, length)
-    po = flash_decode_pallas(q, k, v, length, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(ro, np.float32), np.asarray(po, np.float32),
-        rtol=3e-2, atol=3e-2,
-    )
-
-
-def test_flash_decode_skips_invalid_tail(rng):
-    """Positions beyond `length` must not affect the output at all."""
-    q = _rand(rng, (4, 32), jnp.float32)
-    k = _rand(rng, (256, 2, 32), jnp.float32)
-    v = _rand(rng, (256, 2, 32), jnp.float32)
-    o1 = flash_decode_pallas(q, k, v, 100, interpret=True)
-    k2 = k.at[100:].set(1e6)  # garbage in the masked region
-    v2 = v.at[100:].set(-1e6)
-    o2 = flash_decode_pallas(q, k2, v2, 100, interpret=True)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
-
-
 # ------------------------------------------------------------ ops dispatch
 
 
@@ -653,20 +619,3 @@ def test_sim_best_edge_property(r, c, d, labels, seed):
     np.testing.assert_array_equal(np.asarray(rj), np.asarray(pj))
     np.testing.assert_allclose(np.asarray(rs_), np.asarray(ps),
                                rtol=1e-4, atol=1e-4)
-
-
-@settings(max_examples=20, deadline=None)
-@given(
-    s=st.integers(2, 128), hk=st.sampled_from([1, 2, 4]),
-    g=st.sampled_from([1, 2, 3]), dh=st.sampled_from([8, 16, 32]),
-    frac=st.floats(0.01, 1.0), seed=st.integers(0, 2**31 - 1),
-)
-def test_flash_decode_property(s, hk, g, dh, frac, seed):
-    r = np.random.default_rng(seed)
-    length = max(1, int(s * frac))
-    q = jnp.asarray(r.normal(size=(hk * g, dh)).astype(np.float32))
-    k = jnp.asarray(r.normal(size=(s, hk, dh)).astype(np.float32))
-    v = jnp.asarray(r.normal(size=(s, hk, dh)).astype(np.float32))
-    ro = ref.flash_decode(q, k, v, length)
-    po = flash_decode_pallas(q, k, v, length, interpret=True)
-    np.testing.assert_allclose(np.asarray(ro), np.asarray(po), rtol=1e-3, atol=1e-3)
